@@ -11,8 +11,7 @@ trace replay) hold only while a handful of *conventions* hold:
   * every mutable core field is covered by ``snapshot()``/``restore()``,
   * control broadcasts ride ``ctrl_seq``, never per-client ``srv_seq``
     (the PR-4 divergence bug),
-  * Pallas kernels import compiler params through the compat shim and
-    check grid divisibility.
+  * Pallas kernels check grid divisibility.
 
 ``expolint`` turns those conventions into CI-enforced rules:
 

@@ -1,27 +1,18 @@
 """pallas-rules: kernel hygiene for the TPU Pallas layer.
 
-Two invariants, both born from real breakage:
+**grid divisibility** — a ``pallas_call`` grid computed with ``//``
+silently drops the remainder: ``grid=(S // block,)`` with
+``S % block != 0`` skips the tail elements and produces wrong results
+with no error.  Inside any function that invokes ``pl.pallas_call`` — or
+constructs a ``*GridSpec`` (e.g. ``pltpu.PrefetchScalarGridSpec``), which
+carries a grid to a ``pallas_call`` elsewhere — every floor division must
+be paired with a matching ``lhs % rhs`` check (assert or comparison) over
+the same operands in the same function.  Floor divisions inside
+``lambda`` index maps are exempt — Pallas index maps legitimately map
+block indices with ``//``.
 
-  1. **compiler-params indirection** — the Pallas TPU compiler-params
-     class was renamed upstream (``TPUCompilerParams`` ->
-     ``CompilerParams``), which broke every kernel that touched it
-     directly (fixed in PR 4).  All access must go through
-     ``kernels/pallas_compat.py``, the one module allowed to probe the
-     installed API.  This rule flags direct imports or attribute reads
-     of ``*CompilerParams`` from ``jax.experimental.pallas.tpu``
-     anywhere else under ``src/repro/``.
-
-  2. **grid divisibility** — a ``pallas_call`` grid computed with ``//``
-     silently drops the remainder: ``grid=(S // block,)`` with
-     ``S % block != 0`` skips the tail elements and produces wrong
-     results with no error.  Inside any function that invokes
-     ``pl.pallas_call`` — or constructs a ``*GridSpec`` (e.g.
-     ``pltpu.PrefetchScalarGridSpec``), which carries a grid to a
-     ``pallas_call`` elsewhere — every floor division must be paired
-     with a matching ``lhs % rhs`` check (assert or comparison) over the
-     same operands in the same function.  Floor divisions inside
-     ``lambda`` index maps are exempt — Pallas index maps legitimately
-     map block indices with ``//``.
+Mosaic's block-tiling rules are not checked here: the TPU compile
+rehearsal in ``tests/test_tpu_compile.py`` applies the compiler itself.
 """
 from __future__ import annotations
 
@@ -30,17 +21,6 @@ import ast
 from repro.analysis.framework import Project, Rule, Violation
 
 SRC_GLOB = "src/repro/**/*.py"
-COMPAT = "src/repro/kernels/pallas_compat.py"
-
-_PALLAS_TPU = "jax.experimental.pallas.tpu"
-
-
-def _lambda_spans(func: ast.FunctionDef) -> list[tuple[int, int]]:
-    spans = []
-    for node in ast.walk(func):
-        if isinstance(node, ast.Lambda):
-            spans.append((node.lineno, node.end_lineno or node.lineno))
-    return spans
 
 
 def _nodes_in_lambdas(func: ast.FunctionDef) -> set[int]:
@@ -72,74 +52,16 @@ def _uses_pallas_call(func: ast.FunctionDef) -> bool:
 
 class PallasRulesRule(Rule):
     name = "pallas-rules"
-    description = ("compiler params only via kernels/pallas_compat.py; "
-                   "pallas_call grids built with // need a matching % "
+    description = ("pallas_call grids built with // need a matching % "
                    "divisibility check")
 
     def check(self, project: Project) -> list[Violation]:
         out: list[Violation] = []
         for path in project.glob(SRC_GLOB):
-            if path == COMPAT:
-                continue
             tree = project.tree(path)
             if tree is None:
                 continue
-            out.extend(self._check_compiler_params(path, tree))
             out.extend(self._check_divisibility(path, tree))
-        return out
-
-    # ------------------------------------------------------------------
-    # compiler-params access must go through pallas_compat
-    # ------------------------------------------------------------------
-    def _check_compiler_params(self, path: str,
-                               tree: ast.AST) -> list[Violation]:
-        out: list[Violation] = []
-        tpu_aliases: set[str] = set()
-        for node in ast.walk(tree):
-            if isinstance(node, ast.ImportFrom):
-                if node.module == _PALLAS_TPU:
-                    for alias in node.names:
-                        if "CompilerParams" in alias.name:
-                            out.append(self.violation(
-                                path, node,
-                                f"direct import of `{alias.name}` from "
-                                f"`{_PALLAS_TPU}` — the upstream name "
-                                "drifts; resolve it via "
-                                "kernels/pallas_compat.py"))
-                elif node.module == "jax.experimental.pallas":
-                    for alias in node.names:
-                        if alias.name == "tpu":
-                            tpu_aliases.add(alias.asname or alias.name)
-            elif isinstance(node, ast.Import):
-                for alias in node.names:
-                    if alias.name == _PALLAS_TPU:
-                        tpu_aliases.add(
-                            alias.asname or alias.name.split(".")[0])
-        if not tpu_aliases:
-            return out
-        for node in ast.walk(tree):
-            if isinstance(node, ast.Attribute) \
-                    and "CompilerParams" in node.attr \
-                    and isinstance(node.value, ast.Name) \
-                    and node.value.id in tpu_aliases:
-                out.append(self.violation(
-                    path, node,
-                    f"direct access to `{node.value.id}.{node.attr}` — "
-                    "the upstream name drifts; resolve it via "
-                    "kernels/pallas_compat.py"))
-            elif isinstance(node, ast.Call) \
-                    and isinstance(node.func, ast.Name) \
-                    and node.func.id == "getattr" and len(node.args) >= 2 \
-                    and isinstance(node.args[0], ast.Name) \
-                    and node.args[0].id in tpu_aliases \
-                    and isinstance(node.args[1], ast.Constant) \
-                    and isinstance(node.args[1].value, str) \
-                    and "CompilerParams" in node.args[1].value:
-                out.append(self.violation(
-                    path, node,
-                    f"getattr probe for `{node.args[1].value}` outside "
-                    "kernels/pallas_compat.py — centralize the API-drift "
-                    "probe there"))
         return out
 
     # ------------------------------------------------------------------

@@ -105,6 +105,7 @@ class DryRunCellTask(AbstractTask):
                                     for k, v in self.variant.items()]
         env = dict(os.environ)
         env["REPRO_DRYRUN_DEVICES"] = str(self.devices)
+        env["JAX_PLATFORMS"] = "cpu"        # host devices, never the chip
         env.setdefault("PYTHONPATH", "src")
 
         # run in its own process group so a worker-level kill reaps it

@@ -5,9 +5,13 @@ TPU-native design (not a CUDA port):
     innermost 'arbitrary' grid axis so the online-softmax accumulators live
     in VMEM scratch across KV steps (TPU has no cross-core shared memory —
     the accumulation pattern replaces the CUDA warp-level reduction).
-  * BlockSpecs tile Q/K/V into VMEM: (1, block_q, 1, head_dim) blocks keep
-    the working set (~2·block·D + block_q·block_k fp32) well under 16 MB
-    VMEM for 128x128 blocks at D<=256.
+  * the kernel reads head-major [B, H|K, S, D] operands (the wrapper
+    transposes the model's [B, S, H|K, D] activations), so the last two
+    dims of every (1, 1, block, D) block are (block, D): Mosaic tiles
+    those onto (8, 128) vregs, which a size-1 slice of a head axis in
+    the second-minor position cannot satisfy.  The working set
+    (~2·block·D + block_q·block_k fp32) stays well under 16 MB VMEM for
+    128x128 blocks at D<=256.
   * block_q/block_k default to 128 — MXU-aligned (128x128 systolic array).
   * GQA: the KV head index is derived in the index_map (h // group) so no
     K/V replication is materialised.
@@ -24,9 +28,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
+
+
+def _head_major(x):
+    """[B, S, H, D] <-> [B, H, S, D]."""
+    return jnp.swapaxes(x, 1, 2)
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -45,8 +52,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     k_start = ik * block_k
 
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)            # [bq, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # [bk, D]
+        q = q_ref[0, 0].astype(jnp.float32)                  # [bq, D]
+        k = k_ref[0, 0].astype(jnp.float32)                  # [bk, D]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale       # [bq, bk]
@@ -57,17 +64,17 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             mask = jnp.logical_and(mask, kpos <= qpos)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev, l_prev = m_scr[...], l_scr[...]
-        m_cur = jnp.max(s, axis=1)
+        m_prev, l_prev = m_scr[...], l_scr[...]              # [bq, 1]
+        m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)            # [bk, Dv]
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        v = v_ref[0, 0].astype(jnp.float32)                  # [bk, Dv]
         pv = jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
+        acc_scr[...] = acc_scr[...] * alpha + pv
         m_scr[...] = m_new
         l_scr[...] = l_new
 
@@ -81,7 +88,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     def _finalize():
         l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
@@ -103,28 +110,29 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
         _kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, q_offset=q_offset, kv_len=Sk)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, D),
-                         lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda b, h, iq, ik, G=G: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, Dv),
-                         lambda b, h, iq, ik, G=G: (b, ik, h // G, 0)),
+            pl.BlockSpec((1, 1, block_q, D),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_k, D),
+                         lambda b, h, iq, ik, G=G: (b, h // G, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, Dv),
+                         lambda b, h, iq, ik, G=G: (b, h // G, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, Dv),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((Bsz, Sq, H, Dv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, Dv),
+                               lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bsz, H, Sq, Dv), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
         interpret=interpret,
-    )(q, k, v)
+    )(_head_major(q), _head_major(k), _head_major(v))
+    return _head_major(out)

@@ -1,13 +1,18 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Dispatch policy (this container is CPU-only; TPU is the *target*):
+Dispatch policy:
 
 * backend == 'tpu'      -> compiled Pallas kernel (BlockSpec VMEM tiling)
 * REPRO_PALLAS=interpret -> Pallas kernel body interpreted on CPU (tests)
-* otherwise             -> pure-jnp reference (XLA), bit-for-bit the oracle
+* otherwise             -> pure-jnp reference (XLA), the kernels' oracle
 
 so models always call ``ops.flash_attention`` / ``ops.ssd_scan`` and get the
-best available implementation.
+kernel on a TPU and its reference elsewhere.  Pallas kernels have no
+reverse-mode rule, so the differentiable entry points (``flash_attention``,
+``ssd_scan``) run the kernel forward and differentiate the reference in the
+backward pass (``_kernel_with_ref_vjp``).  XLA cannot partition a Mosaic
+call, so under sharding rules every kernel runs per shard inside
+``shard_map`` (``_per_shard``).
 
 Tuned-config plumbing (``repro.tune``): every block/chunk knob defaults
 to ``None``, meaning "consult the persistent best-config cache for this
@@ -26,6 +31,7 @@ import os
 import jax
 
 from repro.kernels import ref
+from repro.sharding.rules import current_rules
 from repro.tune import cache as _tune_cache
 
 # built-in defaults served on a cache miss — mirrored by
@@ -43,6 +49,64 @@ def _mode() -> str:
     if jax.default_backend() == "tpu":
         return "kernel"
     return "ref"
+
+
+def _kernel_with_ref_vjp(kernel_fn, ref_fn, *args):
+    """``kernel_fn(*args)``, with the gradient of ``ref_fn`` (same math,
+    plain XLA) as its VJP — the backward recomputes the reference."""
+    @jax.custom_vjp
+    def f(*a):
+        return kernel_fn(*a)
+
+    def fwd(*a):
+        return kernel_fn(*a), a
+
+    def bwd(a, g):
+        return jax.vjp(ref_fn, *a)[1](g)
+
+    f.defvjp(fwd, bwd)
+    return f(*args)
+
+
+def _per_shard(fn, args, in_logical, out_logical):
+    """``fn(*args)`` for a Pallas kernel under the active sharding rules.
+
+    With no rules installed this is a plain call.  On a mesh the kernel
+    runs inside ``shard_map``, each device on its block of the axes the
+    rules split.  ``in_logical`` holds one logical spec per argument
+    (``None`` arguments are passed through); ``out_logical`` one spec,
+    or a list with one per output."""
+    rules = current_rules()
+    if rules is None:
+        return fn(*args)
+    live = [i for i, a in enumerate(args) if a is not None]
+
+    def local(*xs):
+        full = list(args)
+        for i, x in zip(live, xs, strict=True):
+            full[i] = x
+        return fn(*full)
+
+    xs = [args[i] for i in live]
+    in_specs = tuple(rules.spec(in_logical[i], args[i].shape) for i in live)
+    out = jax.eval_shape(local, *xs)
+    out_specs = (tuple(rules.spec(lg, o.shape)
+                       for lg, o in zip(out_logical, out, strict=True))
+                 if isinstance(out_logical, list)
+                 else rules.spec(out_logical, out.shape))
+    return jax.shard_map(local, mesh=rules.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*xs)
+
+
+def _head_axis(H: int, K: int) -> str | None:
+    """Logical name for the head axes of a GQA kernel's operands: split
+    over the rules' head axis only when both H and K divide, so every
+    shard keeps whole query groups with their KV head."""
+    rules = current_rules()
+    if rules is None:
+        return None
+    n = rules.axis_size(rules.table.get("heads", ()))
+    return "heads" if H % n == 0 and K % n == 0 else None
 
 
 def _tuned(kernel: str, shape: dict, dtype) -> dict:
@@ -84,29 +148,41 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
         if block_k is None:
             block_k = _fit_block(cfg.get("block_k", _DEFAULT_BLOCK_K),
                                  Sk, _DEFAULT_BLOCK_K)
-    if mode == "ref":
-        # blockwise (flash-style) XLA lowering — same algorithm as the
-        # Pallas kernel, honest HBM profile on non-TPU backends.
-        # (custom_vjp: positional args only)
-        from repro.kernels.xla_flash import blockwise_attention
+    # blockwise (flash-style) XLA lowering — same algorithm as the
+    # Pallas kernel, honest HBM profile on non-TPU backends.
+    # (custom_vjp: positional args only)
+    from repro.kernels.xla_flash import blockwise_attention
 
+    def xla(q, k, v):
         return blockwise_attention(q, k, v, causal, scale, q_offset,
                                    max(block_k, 512))
+
+    if mode == "ref":
+        return xla(q, k, v)
     from repro.kernels import flash_attention as fk
 
-    return fk.flash_attention(
-        q, k, v, causal=causal, scale=scale, q_offset=q_offset,
-        block_q=block_q, block_k=block_k, interpret=(mode == "interpret"),
-    )
+    def call(q, k, v):
+        return fk.flash_attention(
+            q, k, v, causal=causal, scale=scale, q_offset=q_offset,
+            block_q=block_q, block_k=block_k,
+            interpret=(mode == "interpret"))
+
+    spec = ("batch", None, _head_axis(H, K), None)
+
+    def kernel(q, k, v):
+        return _per_shard(call, (q, k, v), (spec,) * 3, spec)
+
+    return _kernel_with_ref_vjp(kernel, xla, q, k, v)
 
 
 def decode_attention(q, k, v, kv_len, *, scale: float | None = None,
                      block_k: int | None = None):
     """Sq=1 GQA decode attention over a ragged KV cache.
 
-    q: [B,H,D], k/v: [B,Sk,K,D/Dv], kv_len: [B] int32 -> [B,H,Dv].  Same
-    dispatch policy as ``flash_attention``: the pure-jnp reference is the
-    XLA fallback on non-TPU backends, the Pallas decode kernel
+    q: [B,H,D], k/v: [K,B,Sk,D/Dv] (the kv-head-major cache layout),
+    kv_len: [B] int32 -> [B,H,Dv].  Same dispatch policy as
+    ``flash_attention``: the pure-jnp reference on non-TPU backends, the
+    Pallas decode kernel
     (``kernels/decode_attention.py``) runs on TPU or under
     ``REPRO_PALLAS=interpret``.  ``block_k``: explicit > tuned > 128
     (the wrapper zero-pads Sk, so any positive tuned value is valid)."""
@@ -115,7 +191,7 @@ def decode_attention(q, k, v, kv_len, *, scale: float | None = None,
         return ref.decode_attention_ref(q, k, v, kv_len, scale=scale)
     if block_k is None:
         Bsz, H, D = q.shape
-        Sk, K = k.shape[1], k.shape[2]
+        K, Sk = k.shape[0], k.shape[2]
         cfg = _tuned("decode_attention",
                      {"b": Bsz, "sk": Sk, "h": H, "kvh": K, "d": D},
                      q.dtype)
@@ -124,15 +200,22 @@ def decode_attention(q, k, v, kv_len, *, scale: float | None = None,
             block_k = _DEFAULT_DECODE_BLOCK_K
     from repro.kernels import decode_attention as dk
 
-    return dk.decode_attention(q, k, v, kv_len, scale=scale, block_k=block_k,
-                               interpret=(mode == "interpret"))
+    def call(q, k, v, kv_len):
+        return dk.decode_attention(q, k, v, kv_len, scale=scale,
+                                   block_k=block_k,
+                                   interpret=(mode == "interpret"))
+
+    cache = (None, "batch", None, None)
+    return _per_shard(call, (q, k, v, kv_len),
+                      (("batch", None, None), cache, cache, ("batch",)),
+                      ("batch", None, None))
 
 
 def decode_attention_paged(q, k_pool, v_pool, page_table, kv_len, *,
                            scale: float | None = None):
     """Sq=1 GQA decode attention against a paged KV pool.
 
-    q: [B,H,D], k_pool/v_pool: [P,ps,K,D/Dv], page_table: [B,W] int32,
+    q: [B,H,D], k_pool/v_pool: [K,P,ps,D/Dv], page_table: [B,W] int32,
     kv_len: [B] int32 -> [B,H,Dv].  Same dispatch policy as
     ``decode_attention``: the pure-jnp reference (page gather + ragged
     dense attention) on non-TPU backends, the page-table Pallas kernel
@@ -146,9 +229,16 @@ def decode_attention_paged(q, k_pool, v_pool, page_table, kv_len, *,
                                               kv_len, scale=scale)
     from repro.kernels import decode_attention as dk
 
-    return dk.decode_attention_paged(q, k_pool, v_pool, page_table, kv_len,
-                                     scale=scale,
-                                     interpret=(mode == "interpret"))
+    def call(q, k_pool, v_pool, page_table, kv_len):
+        return dk.decode_attention_paged(q, k_pool, v_pool, page_table,
+                                         kv_len, scale=scale,
+                                         interpret=(mode == "interpret"))
+
+    pool = (None,) * 4          # a slot's pages may sit anywhere
+    return _per_shard(call, (q, k_pool, v_pool, page_table, kv_len),
+                      (("batch", None, None), pool, pool, ("batch", None),
+                       ("batch",)),
+                      ("batch", None, None))
 
 
 def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int | None = None, h0=None,
@@ -167,14 +257,28 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int | None = None, h0=None,
         chunk = _fit_block(cfg.get("chunk", _DEFAULT_CHUNK), S,
                            _DEFAULT_CHUNK)
     mode = _mode()
-    if mode == "ref":
+
+    def reference(x, dt, A, Bm, Cm, h0):
         return ref.ssd_chunked_ref(
             x, dt, A, Bm, Cm, chunk=chunk, h0=h0,
             return_final_state=return_final_state)
+
+    if mode == "ref":
+        return reference(x, dt, A, Bm, Cm, h0)
     from repro.kernels import ssd_scan as sk
 
-    return sk.ssd_scan(
-        x, dt, A, Bm, Cm, chunk=chunk, h0=h0,
-        return_final_state=return_final_state,
-        interpret=(mode == "interpret"),
-    )
+    def call(x, dt, A, Bm, Cm, h0):
+        return sk.ssd_scan(
+            x, dt, A, Bm, Cm, chunk=chunk, h0=h0,
+            return_final_state=return_final_state,
+            interpret=(mode == "interpret"))
+
+    rows = ("batch", None, None, None)
+    out = [rows, rows] if return_final_state else rows
+
+    def kernel(x, dt, A, Bm, Cm, h0):
+        return _per_shard(call, (x, dt, A, Bm, Cm, h0),
+                          (rows, ("batch", None, None), (None,), rows, rows,
+                           rows), out)
+
+    return _kernel_with_ref_vjp(kernel, reference, x, dt, A, Bm, Cm, h0)

@@ -44,27 +44,28 @@ def attention_ref(
 
 def decode_attention_ref(
     q: jax.Array,       # [B, H, D]
-    k: jax.Array,       # [B, Sk, K, D]
-    v: jax.Array,       # [B, Sk, K, Dv]
+    k: jax.Array,       # [K, B, Sk, D]
+    v: jax.Array,       # [K, B, Sk, Dv]
     kv_len: jax.Array,  # [B] int32 — position p attended iff p < kv_len
     *,
     scale: float | None = None,
 ) -> jax.Array:
     """Single-token (Sq=1) GQA decode attention over a ragged KV cache.
 
-    fp32 softmax; matches ``kernels/decode_attention.py``.  Every slot must
-    have ``kv_len >= 1`` (an all-masked row would softmax to NaN).
+    K/V are in the decode cache's kv-head-major layout.  fp32 softmax;
+    matches ``kernels/decode_attention.py``.  Every slot must have
+    ``kv_len >= 1`` (an all-masked row would softmax to NaN).
     Returns [B, H, Dv]."""
     B, H, D = q.shape
-    Sk, K = k.shape[1], k.shape[2]
+    K, Sk = k.shape[0], k.shape[2]
     G = H // K
     scale = D ** -0.5 if scale is None else scale
     qg = q.reshape(B, K, G, D).astype(jnp.float32)
-    scores = jnp.einsum("bkgd,bskd->bkgs", qg, k.astype(jnp.float32)) * scale
+    scores = jnp.einsum("bkgd,kbsd->bkgs", qg, k.astype(jnp.float32)) * scale
     mask = jnp.arange(Sk)[None, :] < kv_len[:, None]          # [B, Sk]
     scores = jnp.where(mask[:, None, None, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgs,bskd->bkgd", probs, v.astype(jnp.float32))
+    out = jnp.einsum("bkgs,kbsd->bkgd", probs, v.astype(jnp.float32))
     return out.reshape(B, H, v.shape[-1]).astype(q.dtype)
 
 
@@ -85,21 +86,27 @@ def gather_pages(pool: jax.Array, page_table: jax.Array) -> jax.Array:
     return g.reshape(B, W * pool.shape[1], *pool.shape[2:])
 
 
+def gather_kv_pages(pool: jax.Array, page_table: jax.Array) -> jax.Array:
+    """``gather_pages`` per KV head: [K, P, ps, D] -> [K, B, W*ps, D], the
+    dense decode cache's layout."""
+    return jax.vmap(gather_pages, in_axes=(0, None))(pool, page_table)
+
+
 def decode_attention_paged_ref(
     q: jax.Array,           # [B, H, D]
-    k_pool: jax.Array,      # [P, ps, K, D]
-    v_pool: jax.Array,      # [P, ps, K, Dv]
+    k_pool: jax.Array,      # [K, P, ps, D]
+    v_pool: jax.Array,      # [K, P, ps, Dv]
     page_table: jax.Array,  # [B, W] int32
     kv_len: jax.Array,      # [B] int32
     *,
     scale: float | None = None,
 ) -> jax.Array:
     """Paged Sq=1 decode attention: gather the slot's pages into a dense
-    [B, W*ps, ...] view, then run the ragged dense reference.  Matches
+    [K, B, W*ps, ...] view, then run the ragged dense reference.  Matches
     ``kernels/decode_attention.py::decode_attention_paged``.
     Returns [B, H, Dv]."""
-    k = gather_pages(k_pool, page_table)
-    v = gather_pages(v_pool, page_table)
+    k = gather_kv_pages(k_pool, page_table)
+    v = gather_kv_pages(v_pool, page_table)
     return decode_attention_ref(q, k, v, kv_len, scale=scale)
 
 

@@ -1,9 +1,11 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_EXTRA_XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=" +
                            os.environ.get("REPRO_DRYRUN_DEVICES", "512")).strip()
-# NOTE: the two lines above MUST run before any other import (including
-# jax and repro.*): jax locks the device count on first backend init.
+# NOTE: the lines above MUST run before any other import (including jax
+# and repro.*): jax locks the platform and device count on first backend
+# init.  The dry run compiles on forced host devices, never on a chip.
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production mesh and extract roofline terms from the compiled artifact.

@@ -10,7 +10,8 @@ bytes are NOT in cost_analysis: we parse the partitioned HLO
 all-reduce / reduce-scatter / all-to-all / collective-permute, weighted by
 the ring-algorithm traffic factor (all-reduce moves ~2x its payload).
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware model: the v5e row of ``PEAKS`` — 197 TFLOP/s bf16, 819 GB/s
+HBM, ~50 GB/s/link ICI.
 """
 from __future__ import annotations
 
@@ -20,9 +21,32 @@ from dataclasses import dataclass, field, asdict
 
 import numpy as np
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s / link (conservative single-link)
+# Per-chip peaks keyed by ``jax.devices()[0].device_kind``.  Source: Google
+# Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s chip-to-chip).  ici_bw is this model's conservative
+# single-link share of that interconnect, not a published figure.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+V5E = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Published peaks of one chip of ``device_kind``.  A kind missing
+    from ``PEAKS`` is an error, never a default: a share computed against
+    another chip's peaks would be wrong."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add them to PEAKS "
+                       f"(known: {sorted(PEAKS)})") from None
+
+
+# the dry-run model's target chip
+PEAK_FLOPS = PEAKS[V5E]["flops"]        # bf16 / chip
+HBM_BW = PEAKS[V5E]["hbm_bw"]           # bytes/s / chip
+ICI_BW = PEAKS[V5E]["ici_bw"]           # bytes/s / link
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

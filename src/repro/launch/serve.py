@@ -47,10 +47,12 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from repro.configs import get_config, reduced_config
+    from repro.launch import compile_cache
     from repro.models import lm
     from repro.models.params import init_params
     from repro.serve.engine import DecodeEngine, Request
 
+    compile_cache.enable()
     cfg = (reduced_config(args.arch) if args.preset == "reduced"
            else get_config(args.arch))
     params = init_params(lm.make_lm(cfg), jax.random.PRNGKey(args.seed))

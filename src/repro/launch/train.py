@@ -8,8 +8,9 @@
     REPRO_TRAIN_DEVICES=8 PYTHONPATH=src python -m repro.launch.train \
         --arch smollm-360m --preset reduced --steps 20 --mesh 2 4
 
-On a real TPU slice, drop REPRO_TRAIN_DEVICES and pass the slice topology
-as --mesh; restarts resume from --ckpt-dir automatically (ExpoCloud
+On a TPU host, drop REPRO_TRAIN_DEVICES and pass the chips' mesh as
+--mesh (``python chip_smoke.py --four-chips`` runs that path on a 2x2
+host); restarts resume from --ckpt-dir automatically (ExpoCloud
 reassignment-compatible, see examples/train_lm.py for the task wrapper).
 """
 import os
@@ -41,8 +42,10 @@ def main(argv=None):
 
     from repro.configs import get_config, reduced_config
     from repro.data.synthetic import data_config_for
+    from repro.launch import compile_cache
     from repro.train.loop import TrainJob, run_training
 
+    compile_cache.enable()
     cfg = (reduced_config(args.arch) if args.preset == "reduced"
            else get_config(args.arch))
     dc = data_config_for(cfg, seq_len=args.seq, batch_size=args.batch)

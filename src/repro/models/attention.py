@@ -1,5 +1,10 @@
 """GQA/MQA attention with qk-norm, partial/interleaved RoPE, and a decode
 path against a pre-allocated KV cache.
+
+The KV cache is kv-head-major — dense ``[K, B, max_seq, hd]`` stripes or a
+paged ``[K, num_pages, page_size, hd]`` pool — the layout the Pallas decode
+kernels read, so a decode step writes its new rows in place and never
+transposes the cache.
 """
 from __future__ import annotations
 
@@ -54,7 +59,13 @@ def apply_attention(cfg, p, x, positions):
     out = ops.flash_attention(q, k, v, causal=True)
     out = out.reshape(*x.shape[:2], cfg.q_dim)
     out = shard(out, "batch", "seq", "heads")
-    return out @ p["wo"], (k, v)
+    return out @ p["wo"], (_kv_major(k), _kv_major(v))
+
+
+def _kv_major(x):
+    """Move the kv-head axis of [B, (S,) K, hd] rows to the front, the
+    cache's layout."""
+    return jnp.moveaxis(x, -2, 0)
 
 
 def make_kv_cache(cfg, batch: int, max_seq: int, stack: tuple = ()):
@@ -62,8 +73,8 @@ def make_kv_cache(cfg, batch: int, max_seq: int, stack: tuple = ()):
     abstract_params)."""
     lead = tuple(stack)
     lead_logical = (None,) * len(lead)
-    shape = (*lead, batch, max_seq, cfg.num_kv_heads, cfg.head_dim)
-    logical = (*lead_logical, "batch", "seq_kv", "kv_heads", None)
+    shape = (*lead, cfg.num_kv_heads, batch, max_seq, cfg.head_dim)
+    logical = (*lead_logical, "kv_heads", "batch", "seq_kv", None)
     return {
         "k": Param(shape, logical, init="zeros", dtype=cfg.dtype),
         "v": Param(shape, logical, init="zeros", dtype=cfg.dtype),
@@ -79,8 +90,8 @@ def make_kv_cache_paged(cfg, num_pages: int, page_size: int,
     slots × max_seq."""
     lead = tuple(stack)
     lead_logical = (None,) * len(lead)
-    shape = (*lead, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim)
-    logical = (*lead_logical, None, "seq_kv", "kv_heads", None)
+    shape = (*lead, cfg.num_kv_heads, num_pages, page_size, cfg.head_dim)
+    logical = (*lead_logical, "kv_heads", None, "seq_kv", None)
     return {
         "k": Param(shape, logical, init="zeros", dtype=cfg.dtype),
         "v": Param(shape, logical, init="zeros", dtype=cfg.dtype),
@@ -119,17 +130,24 @@ def paged_write_rows(pool, page_table, positions, values, active=None):
     return rows.reshape(pool.shape)
 
 
+def paged_write_kv(pool, page_table, positions, values, active=None):
+    """``paged_write_rows`` per KV head: pool [K, P, ps, hd]; values
+    [B, (C,) K, hd]."""
+    return jax.vmap(paged_write_rows, in_axes=(0, None, None, -2, None))(
+        pool, page_table, positions, values, active)
+
+
 def apply_attention_decode_paged(cfg, p, x, cache, pos, page_table,
                                  active=None):
     """One-token decode against the paged pool.  x: [B, 1, d]; cache:
-    {k,v: [P, ps, K, hd]}; pos: [B] int32; page_table: [B, W] int32
+    {k,v: [K, P, ps, hd]}; pos: [B] int32; page_table: [B, W] int32
     (traced — constant within a fused sync, updated by the engine's
     allocator between syncs); active: optional [B] bool.
     Returns (out, new_cache)."""
     B = x.shape[0]
     q, k_new, v_new = _qkv(cfg, p, x, pos[:, None])
-    k = paged_write_rows(cache["k"], page_table, pos, k_new[:, 0], active)
-    v = paged_write_rows(cache["v"], page_table, pos, v_new[:, 0], active)
+    k = paged_write_kv(cache["k"], page_table, pos, k_new[:, 0], active)
+    v = paged_write_kv(cache["v"], page_table, pos, v_new[:, 0], active)
     out = ops.decode_attention_paged(q[:, 0], k, v, page_table, pos + 1,
                                      scale=cfg.head_dim ** -0.5)
     out = out.reshape(B, 1, cfg.q_dim)
@@ -144,25 +162,25 @@ def apply_attention_prefill_chunk_paged(cfg, p, x, cache, start, page_table,
     the chunk attends to the slot's gathered pages under the usual
     kpos <= start+q mask (stale rows of unwritten pages sit beyond the
     mask).  Returns (out [B, C, d], new_cache)."""
-    from repro.kernels.ref import gather_pages
+    from repro.kernels.ref import gather_kv_pages
 
     B, C, _ = x.shape
     positions = start[:, None] + jnp.arange(C)[None, :]         # [B, C]
     q, k_new, v_new = _qkv(cfg, p, x, positions)
-    k = paged_write_rows(cache["k"], page_table, positions, k_new, active)
-    v = paged_write_rows(cache["v"], page_table, positions, v_new, active)
-    kg = gather_pages(k, page_table)                   # [B, W*ps, K, hd]
-    vg = gather_pages(v, page_table)
-    smax = kg.shape[1]
-    K = kg.shape[2]
+    k = paged_write_kv(cache["k"], page_table, positions, k_new, active)
+    v = paged_write_kv(cache["v"], page_table, positions, v_new, active)
+    kg = gather_kv_pages(k, page_table)                # [K, B, W*ps, hd]
+    vg = gather_kv_pages(v, page_table)
+    smax = kg.shape[2]
+    K = kg.shape[0]
     G = cfg.num_heads // K
     qg = q.reshape(B, C, K, G, cfg.head_dim).astype(jnp.float32)
-    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, kg.astype(jnp.float32))
+    scores = jnp.einsum("bqkgd,kbsd->bkgqs", qg, kg.astype(jnp.float32))
     scores = scores * (cfg.head_dim ** -0.5)
     mask = jnp.arange(smax)[None, None, :] <= positions[:, :, None]
     scores = jnp.where(mask[:, None, None, :, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, vg.astype(jnp.float32))
+    out = jnp.einsum("bkgqs,kbsd->bqkgd", probs, vg.astype(jnp.float32))
     out = out.reshape(B, C, cfg.q_dim).astype(x.dtype)
     return out @ p["wo"], {"k": k, "v": v}
 
@@ -170,7 +188,7 @@ def apply_attention_prefill_chunk_paged(cfg, p, x, cache, start, page_table,
 def apply_attention_prefill_chunk(cfg, p, x, cache, start, active=None):
     """Batched prefill of a C-token chunk into the KV cache.
 
-    x: [B, C, d]; cache: {k,v: [B, Smax, K, hd]}; start: [B] int32 (cache
+    x: [B, C, d]; cache: {k,v: [K, B, Smax, hd]}; start: [B] int32 (cache
     position of the chunk's first token — per-slot, so freshly admitted
     requests prefill while resident slots sit at different fill levels);
     active: optional [B] bool — inactive slots leave the cache untouched
@@ -182,37 +200,39 @@ def apply_attention_prefill_chunk(cfg, p, x, cache, start, active=None):
     B, C, _ = x.shape
     positions = start[:, None] + jnp.arange(C)[None, :]         # [B, C]
     q, k_new, v_new = _qkv(cfg, p, x, positions)
-    smax = cache["k"].shape[1]
+    smax = cache["k"].shape[2]
     wpos = positions if active is None else jnp.where(
         active[:, None], positions, smax)
     b_idx = jnp.arange(B)[:, None]
-    k = cache["k"].at[b_idx, wpos, ...].set(k_new, mode="drop")
-    v = cache["v"].at[b_idx, wpos, ...].set(v_new, mode="drop")
-    K = k.shape[2]
+    k = cache["k"].at[:, b_idx, wpos].set(_kv_major(k_new), mode="drop")
+    v = cache["v"].at[:, b_idx, wpos].set(_kv_major(v_new), mode="drop")
+    K = k.shape[0]
     G = cfg.num_heads // K
     qg = q.reshape(B, C, K, G, cfg.head_dim).astype(jnp.float32)
-    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k.astype(jnp.float32))
+    scores = jnp.einsum("bqkgd,kbsd->bkgqs", qg, k.astype(jnp.float32))
     scores = scores * (cfg.head_dim ** -0.5)
     mask = jnp.arange(smax)[None, None, :] <= positions[:, :, None]
     scores = jnp.where(mask[:, None, None, :, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v.astype(jnp.float32))
+    out = jnp.einsum("bkgqs,kbsd->bqkgd", probs, v.astype(jnp.float32))
     out = out.reshape(B, C, cfg.q_dim).astype(x.dtype)
     return out @ p["wo"], {"k": k, "v": v}
 
 
 def apply_attention_decode(cfg, p, x, cache, pos, active=None):
-    """One-token decode. x: [B, 1, d]; cache: {k,v: [B, Smax, K, hd]};
+    """One-token decode. x: [B, 1, d]; cache: {k,v: [K, B, Smax, hd]};
     pos: [B] int32 (index of the new token); active: optional [B] bool —
     inactive slots leave the cache untouched (continuous batching).
     Returns (out, new_cache)."""
     B = x.shape[0]
     q, k_new, v_new = _qkv(cfg, p, x, pos[:, None])
     b_idx = jnp.arange(B)
-    smax = cache["k"].shape[1]
+    smax = cache["k"].shape[2]
     wpos = pos if active is None else jnp.where(active, pos, smax)
-    k = cache["k"].at[b_idx, wpos, ...].set(k_new[:, 0], mode="drop")
-    v = cache["v"].at[b_idx, wpos, ...].set(v_new[:, 0], mode="drop")
+    k = cache["k"].at[:, b_idx, wpos].set(_kv_major(k_new[:, 0]),
+                                          mode="drop")
+    v = cache["v"].at[:, b_idx, wpos].set(_kv_major(v_new[:, 0]),
+                                          mode="drop")
     # position p attended iff p <= pos, i.e. p < pos + 1 == kv_len.  The
     # dispatcher's ref path is bit-identical to the previous inline einsum
     # formulation; on TPU / REPRO_PALLAS=interpret the Sq=1 Pallas decode

@@ -28,15 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# API drift: shard_map graduated from jax.experimental (check_rep=) to the
-# top level (check_vma=); support both so the EP path runs on either side
-try:
-    from jax import shard_map as _shard_map
-    _SHARD_MAP_NOCHECK = {"check_vma": False}
-except ImportError:                                    # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_NOCHECK = {"check_rep": False}
-
 from repro.models.layers import make_dense_ffn, apply_dense_ffn
 from repro.models.params import Param
 from repro.sharding.rules import current_rules, shard
@@ -234,10 +225,10 @@ def apply_moe_ep(cfg, p, x2d, rules):
         P("model", None, None),      # wg
         P("model", None, None),      # wo  [E, ff, d]
     )
-    fn = partial(_shard_map, mesh=mesh,
+    fn = partial(jax.shard_map, mesh=mesh,
                  in_specs=in_specs,
                  out_specs=(P(dp_spec, None), P()),
-                 **_SHARD_MAP_NOCHECK)(local)
+                 check_vma=False)(local)
     y, aux = fn(x2d, router, bias, wi, wg, wo)
     if m.num_shared_experts:
         y = y + apply_dense_ffn(cfg, p["shared"], x2d)

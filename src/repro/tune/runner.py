@@ -128,8 +128,8 @@ def measure_decode_attention(b, sk, h, kvh, d, dtype, block_k):
     dt = jnp.dtype(dtype)
     kq, kk, kv = _keys("q", "k", "v")
     q = _normal(kq, (b, h, d), dt)
-    k = _normal(kk, (b, sk, kvh, d), dt)
-    v = _normal(kv, (b, sk, kvh, d), dt)
+    k = _normal(kk, (kvh, b, sk, d), dt)
+    v = _normal(kv, (kvh, b, sk, d), dt)
     # ragged fill levels, the serving steady state (deterministic)
     kv_len = jnp.asarray([sk - (i * sk // (2 * b)) for i in range(b)],
                          jnp.int32)
@@ -153,8 +153,8 @@ def measure_decode_attention_paged(b, sk, kvh, g, d, dtype, page_size):
     n_pages = b * w
     kq, kk, kv = _keys("q", "k", "v")
     q = _normal(kq, (b, kvh * g, d), dt)
-    k_pool = _normal(kk, (n_pages, page_size, kvh, d), dt)
-    v_pool = _normal(kv, (n_pages, page_size, kvh, d), dt)
+    k_pool = _normal(kk, (kvh, n_pages, page_size, d), dt)
+    v_pool = _normal(kv, (kvh, n_pages, page_size, d), dt)
     # each slot owns a contiguous page run, shuffled per-slot order is
     # exercised by the serve tests — here geometry cost is the question
     page_table = jnp.arange(n_pages, dtype=jnp.int32).reshape(b, w)

@@ -26,12 +26,11 @@ import random
 from dataclasses import dataclass
 
 from repro.core.space import ParamSpace, axis
+from repro.launch.roofline import HBM_BW, PEAK_FLOPS
 
-# hardware model (TPU v5e, same constants as launch/roofline.py) + a
-# per-grid-cell launch overhead term — the block knobs trade this
-# overhead against memory traffic, which is the whole tuning surface
-PEAK_FLOPS = 197e12           # FLOP/s
-HBM_BW = 819e9                # bytes/s
+# hardware model (the v5e peaks of launch/roofline.py) + a per-grid-cell
+# launch overhead term — the block knobs trade this overhead against
+# memory traffic, which is the whole tuning surface
 CELL_OVERHEAD_US = 0.2        # per pallas grid cell
 
 # virtual seconds per predicted microsecond when the sweep runs on the

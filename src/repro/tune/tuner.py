@@ -90,8 +90,20 @@ def _config_of(cell: dict, tunables: tuple) -> dict:
     return {k: cell[k] for k in tunables}
 
 
+_LOCAL_ON_TPU = (
+    "engine='local' measures in forked client processes, and a TPU chip "
+    "belongs to one process at a time; on a TPU, tune with engine='sim', "
+    "which measures every surviving config in this process")
+
+
 def _measure_entry(kernel: str, cell: dict, q) -> None:
-    """Spawned-subprocess target: measure one cell, ship the result back."""
+    """Spawned-subprocess target: measure one cell, ship the result back
+    (or the refusal, on a TPU backend)."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        q.put(RuntimeError(_LOCAL_ON_TPU))
+        return
     from repro.tune import runner
 
     q.put(runner.measure_cell(kernel, cell))
@@ -102,7 +114,8 @@ def _measure_incumbent(kernel: str, cell: dict, engine: str):
     a *spawned* subprocess: the LocalEngine forks its client processes,
     and a parent that has already initialised jax (multithreaded) would
     hand every forked client a deadlocked runtime — the tuner parent must
-    stay jax-free until the sweep is over."""
+    stay jax-free until the sweep is over.  The child refuses a TPU
+    backend: the forked clients would contend for the one chip."""
     if engine != "local":
         return _runner.measure_cell(kernel, cell)
     import multiprocessing
@@ -117,6 +130,8 @@ def _measure_incumbent(kernel: str, cell: dict, engine: str):
         p.join(timeout=10.0)
         if p.is_alive():
             p.kill()
+    if isinstance(result, Exception):
+        raise result
     return result
 
 

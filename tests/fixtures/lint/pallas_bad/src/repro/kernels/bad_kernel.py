@@ -1,12 +1,8 @@
-"""Fixture: direct compiler-params access + an unchecked // grid."""
+"""Fixture: an unchecked // grid."""
 import jax.experimental.pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-from jax.experimental.pallas.tpu import TPUCompilerParams  # noqa: F401
 
 
 def bad_kernel(x, block=128):
     S = x.shape[0]
-    params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
     grid = (S // block,)
-    return pl.pallas_call(lambda x_ref, o_ref: None, grid=grid,
-                          compiler_params=params)(x)
+    return pl.pallas_call(lambda x_ref, o_ref: None, grid=grid)(x)

@@ -61,16 +61,17 @@ RESHARD = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.checkpoint import checkpointer as ck
+    from repro.launch.mesh import make_mesh
 
     tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
     # save from a 4-way model sharding
-    mesh1 = jax.make_mesh((4,), ("model",))
+    mesh1 = make_mesh((4,), ("model",))
     sh1 = {"w": NamedSharding(mesh1, P("model", None))}
     t1 = jax.tree_util.tree_map(jax.device_put, tree, sh1)
     ck.save("@DIR@", 1, t1)
 
     # restore onto a DIFFERENT mesh (2x2) and sharding (elastic restart)
-    mesh2 = jax.make_mesh((2, 2), ("data", "model"))
+    mesh2 = make_mesh((2, 2), ("data", "model"))
     sh2 = {"w": NamedSharding(mesh2, P("data", "model"))}
     like = {"w": jax.ShapeDtypeStruct((8, 8), jnp.float32)}
     restored, step, _ = ck.restore("@DIR@", like, shardings=sh2)

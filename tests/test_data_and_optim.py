@@ -104,13 +104,13 @@ def test_int8_allreduce_matches_mean_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.sharding.compression import allreduce_int8
 
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         x = jax.random.normal(jax.random.PRNGKey(0), (4, 64))
 
-        f = shard_map(lambda s: allreduce_int8(s, "data"), mesh=mesh,
+        f = jax.shard_map(lambda s: allreduce_int8(s, "data"), mesh=mesh,
                       in_specs=P("data", None), out_specs=P("data", None))
         out = f(x)
         want = jnp.broadcast_to(jnp.mean(x, 0, keepdims=True), x.shape)

@@ -12,14 +12,14 @@ import pytest
 from repro.kernels.decode_attention import (decode_attention,
                                             decode_attention_paged)
 from repro.kernels.ref import (decode_attention_paged_ref,
-                               decode_attention_ref, gather_pages)
+                               decode_attention_ref, gather_kv_pages)
 
 
 def _inputs(B, Sk, H, K, D, seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
-    k = jax.random.normal(ks[1], (B, Sk, K, D), jnp.float32)
-    v = jax.random.normal(ks[2], (B, Sk, K, D), jnp.float32)
+    k = jax.random.normal(ks[1], (K, B, Sk, D), jnp.float32)
+    v = jax.random.normal(ks[2], (K, B, Sk, D), jnp.float32)
     return q, k, v
 
 
@@ -43,9 +43,9 @@ def test_ragged_kv_len_masks_cache_tail():
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
     # tail beyond kv_len must not influence the output at all
-    k2 = k.at[:, 40:].set(1e4)
-    v2 = v.at[:, 40:].set(-1e4)
-    got2 = decode_attention(q[:2], k2[:2], v2[:2], kv_len[:2],
+    k2 = k.at[:, :, 40:].set(1e4)
+    v2 = v.at[:, :, 40:].set(-1e4)
+    got2 = decode_attention(q[:2], k2[:, :2], v2[:, :2], kv_len[:2],
                             block_k=32, interpret=True)
     np.testing.assert_array_equal(np.asarray(got2), np.asarray(got[:2]))
 
@@ -86,15 +86,15 @@ def test_ops_dispatch_ref_matches_kernel(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# paged variant: K/V live in a [P, ps, K, D] pool, steered by page tables
+# paged variant: K/V live in a [K, P, ps, D] pool, steered by page tables
 # ---------------------------------------------------------------------------
 def _paged_inputs(B, W, ps, H, K, D, num_pages, seed=0):
     """Pool + *shuffled* page tables: each slot's pages are scattered over
     the pool so physical contiguity can't mask indexing bugs."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
-    k_pool = jax.random.normal(ks[1], (num_pages, ps, K, D), jnp.float32)
-    v_pool = jax.random.normal(ks[2], (num_pages, ps, K, D), jnp.float32)
+    k_pool = jax.random.normal(ks[1], (K, num_pages, ps, D), jnp.float32)
+    v_pool = jax.random.normal(ks[2], (K, num_pages, ps, D), jnp.float32)
     rng = np.random.default_rng(seed)
     perm = rng.permutation(num_pages)[:B * W]
     table = jnp.asarray(perm.reshape(B, W).astype(np.int32))
@@ -114,13 +114,13 @@ def test_paged_matches_paged_ref(H, K):
 
 def test_paged_matches_dense_on_gathered_layout():
     """The paged kernel over a shuffled table must equal the dense ref over
-    the gathered [B, W*ps, K, D] view — same math, different addressing."""
+    the gathered [K, B, W*ps, D] view — same math, different addressing."""
     B, W, ps, H, K, D = 3, 5, 4, 8, 2, 16
     q, kp, vp, pt = _paged_inputs(B, W, ps, H, K, D, num_pages=32, seed=1)
     kv_len = jnp.array([1, 7, 20], jnp.int32)
     got = decode_attention_paged(q, kp, vp, pt, kv_len, interpret=True)
-    ref = decode_attention_ref(q, gather_pages(kp, pt),
-                               gather_pages(vp, pt), kv_len)
+    ref = decode_attention_ref(q, gather_kv_pages(kp, pt),
+                               gather_kv_pages(vp, pt), kv_len)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -139,10 +139,11 @@ def test_paged_masks_unwritten_page_tail():
         for j in range(W):
             lo, hi = j * ps, min((j + 1) * ps, live)
             pg = int(pt[b, j])
-            keep_k = kp[pg, :max(0, hi - lo)]
-            keep_v = vp[pg, :max(0, hi - lo)]
-            kp2 = kp2.at[pg].set(1e4).at[pg, :max(0, hi - lo)].set(keep_k)
-            vp2 = vp2.at[pg].set(-1e4).at[pg, :max(0, hi - lo)].set(keep_v)
+            n = max(0, hi - lo)
+            keep_k = kp[:, pg, :n]
+            keep_v = vp[:, pg, :n]
+            kp2 = kp2.at[:, pg].set(1e4).at[:, pg, :n].set(keep_k)
+            vp2 = vp2.at[:, pg].set(-1e4).at[:, pg, :n].set(keep_v)
     got = decode_attention_paged(q, kp2, vp2, pt, kv_len, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
 

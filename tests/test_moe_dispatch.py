@@ -64,6 +64,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import reduced_config
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.models.params import init_params, param_shardings
 from repro.sharding.rules import make_rules, use_rules
@@ -76,7 +77,7 @@ tok = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.vocab_size)
 batch = {"tokens": tok}
 os.environ["REPRO_MOE"] = "gather"
 ref, _ = jax.jit(lambda p, b: lm.train_loss(cfg, p, b, remat=False))(params, batch)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 rules = make_rules(mesh)
 psh = param_shardings(descr, rules)
 ps = jax.tree_util.tree_map(jax.device_put, params, psh)

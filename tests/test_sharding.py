@@ -11,9 +11,9 @@ from jax.sharding import PartitionSpec as P
 
 def test_rules_resolution_and_divisibility(monkeypatch):
     # build rules without touching global device state: fake mesh-like
-    import jax
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((1,), ("model",))  # 1 real CPU device
+    mesh = make_mesh((1,), ("model",))  # 1 real CPU device
     from repro.sharding.rules import make_rules
 
     rules = make_rules(mesh)
@@ -47,6 +47,7 @@ SUBPROCESS_PARITY = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import reduced_config
+    from repro.launch.mesh import make_mesh
     from repro.models import lm
     from repro.models.params import init_params, param_shardings
     from repro.sharding.rules import make_rules, use_rules
@@ -70,7 +71,7 @@ SUBPROCESS_PARITY = textwrap.dedent("""
     loss1 = float(m1["loss"])
 
     # sharded result on a 2x4 mesh
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = make_rules(mesh)
     psh = param_shardings(descr, rules)
     osh = opt_state_shardings("adamw", descr, rules, zero1=True)
@@ -106,3 +107,64 @@ def test_sharded_train_step_parity_subprocess(arch):
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
     assert "PARITY_OK" in r.stdout
+
+
+PER_SHARD = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    os.environ["REPRO_PALLAS"] = "interpret"
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.kernels import ops
+    from repro.launch.mesh import make_mesh
+    from repro.sharding.rules import make_rules, use_rules
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 8)
+    n = lambda i, s: jax.random.normal(ks[i], s, jnp.float32)
+    calls = {
+        "flash": (lambda q, k, v: ops.flash_attention(q, k, v),
+                  (n(0, (4, 32, 4, 16)), n(1, (4, 32, 2, 16)),
+                   n(2, (4, 32, 2, 16)))),
+        "decode": (lambda q, k, v: ops.decode_attention(
+                       q, k, v, jnp.array([3, 9, 16, 30], jnp.int32)),
+                   (n(3, (4, 4, 16)), n(4, (2, 4, 32, 16)),
+                    n(5, (2, 4, 32, 16)))),
+        "ssd": (lambda x, Bm, Cm: ops.ssd_scan(
+                    x, jnp.full(x.shape[:3], 0.5), -jnp.ones(4), Bm, Cm,
+                    chunk=16, return_final_state=True),
+                (n(6, (4, 32, 4, 8)), n(7, (4, 32, 1, 8)),
+                 n(1, (4, 32, 1, 8)))),
+    }
+    rules = make_rules(make_mesh((2, 2), ("data", "model")))
+    for name, (fn, args) in calls.items():
+        want = jax.jit(fn)(*args)
+        def sharded(*a, fn=fn):
+            with use_rules(rules):
+                return fn(*a)
+        got = jax.jit(sharded)(*args)
+        for g, w in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want), strict=True):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       atol=1e-5, rtol=1e-5, err_msg=name)
+    # the differentiable kernels take gradients per shard too
+    q, k, v = calls["flash"][1]
+    def loss(q, rules=None):
+        with use_rules(rules):
+            return jnp.sum(ops.flash_attention(q, k, v) ** 2)
+    np.testing.assert_allclose(
+        np.asarray(jax.jit(jax.grad(lambda q: loss(q, rules)))(q)),
+        np.asarray(jax.jit(jax.grad(loss))(q)), atol=1e-4, rtol=1e-4)
+    print("PER_SHARD_OK")
+""")
+
+
+def test_kernels_run_per_shard_subprocess():
+    """Under sharding rules every Pallas kernel runs per shard inside
+    shard_map (a Mosaic call cannot be partitioned by XLA) and matches the
+    unsharded call."""
+    env = dict(os.environ, PYTHONPATH="src")
+    r = subprocess.run([sys.executable, "-c", PER_SHARD],
+                       capture_output=True, text=True, env=env, timeout=300,
+                       cwd=os.path.dirname(os.path.dirname(
+                           os.path.abspath(__file__))))
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+    assert "PER_SHARD_OK" in r.stdout
