@@ -19,8 +19,8 @@ query token.  This kernel is shaped for the serving fast path instead:
 K/V are read in the cache's own kv-head-major layout, [K, B, Sk, D], so
 every (1, 1, block_k, D) block ends in (block_k, D) as Mosaic's tiling
 needs, and the decode step never transposes the cache.  Non-dividing Sk
-is handled by zero-padding K/V up to a block multiple in the wrapper; the pad region sits beyond every ``kv_len`` so the masking
-covers it.  The grid divisibility is asserted after padding (expolint
+is handled by zero-padding K/V up to a block multiple in the wrapper;
+the pad region sits beyond every ``kv_len`` so the masking covers it.  The grid divisibility is asserted after padding (expolint
 pallas-rules).
 
 ``decode_attention_paged`` is the same online-softmax loop over a *paged*

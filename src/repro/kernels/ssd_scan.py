@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
 _DT_ROWS = 8   # chunks of dt per block: one sublane tile
 
 
@@ -71,7 +70,7 @@ def _kernel(A_ref, x_ref, dt_ref, b_ref, c_ref, h0_ref, y_ref, hT_ref,
     # cum_l = sum_{s <= l} dA_s, as a column and as a row
     cum = jnp.sum(jnp.where(si <= li, jnp.broadcast_to(dA, (L, L)), 0.0),
                   axis=1, keepdims=True)            # [L, 1]
-    cum_row = _flip(cum)                          # [1, L]
+    cum_row = _flip(cum)                            # [1, L]
     cum_last = cum_row[:, L - 1:L]                  # [1, 1]
     # intra-chunk: scores[l, s] = C_l·B_s · exp(cum_l - cum_s) · dt_s, s<=l
     cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
@@ -88,7 +87,7 @@ def _kernel(A_ref, x_ref, dt_ref, b_ref, c_ref, h0_ref, y_ref, hT_ref,
         preferred_element_type=jnp.float32)
 
     # state update: h_out = exp(cum_L) h_in + sum_s exp(cum_L - cum_s) dt_s x_s B_sᵀ
-    w = jnp.exp(cum_last - cum) * _flip(dt)       # [L, 1]
+    w = jnp.exp(cum_last - cum) * _flip(dt)         # [L, 1]
     state_in = jax.lax.dot_general(
         x * w, Bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)         # [P, N]
