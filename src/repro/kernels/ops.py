@@ -189,9 +189,9 @@ def decode_attention(q, k, v, kv_len, *, scale: float | None = None,
     mode = _mode()
     if mode in ("ref", "naive"):
         return ref.decode_attention_ref(q, k, v, kv_len, scale=scale)
+    Bsz, H, D = q.shape
+    K, Sk = k.shape[0], k.shape[2]
     if block_k is None:
-        Bsz, H, D = q.shape
-        K, Sk = k.shape[0], k.shape[2]
         cfg = _tuned("decode_attention",
                      {"b": Bsz, "sk": Sk, "h": H, "kvh": K, "d": D},
                      q.dtype)
@@ -205,10 +205,12 @@ def decode_attention(q, k, v, kv_len, *, scale: float | None = None,
                                    block_k=block_k,
                                    interpret=(mode == "interpret"))
 
-    cache = (None, "batch", None, None)
+    # split heads the way the cache is stored, so it is never gathered
+    h = _head_axis(H, K)
+    cache = (h, "batch", None, None)
     return _per_shard(call, (q, k, v, kv_len),
-                      (("batch", None, None), cache, cache, ("batch",)),
-                      ("batch", None, None))
+                      (("batch", h, None), cache, cache, ("batch",)),
+                      ("batch", h, None))
 
 
 def decode_attention_paged(q, k_pool, v_pool, page_table, kv_len, *,
@@ -234,11 +236,12 @@ def decode_attention_paged(q, k_pool, v_pool, page_table, kv_len, *,
                                          kv_len, scale=scale,
                                          interpret=(mode == "interpret"))
 
-    pool = (None,) * 4          # a slot's pages may sit anywhere
+    h = _head_axis(q.shape[1], k_pool.shape[0])
+    pool = (h, None, None, None)    # a slot's pages may sit anywhere
     return _per_shard(call, (q, k_pool, v_pool, page_table, kv_len),
-                      (("batch", None, None), pool, pool, ("batch", None),
+                      (("batch", h, None), pool, pool, ("batch", None),
                        ("batch",)),
-                      ("batch", None, None))
+                      ("batch", h, None))
 
 
 def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int | None = None, h0=None,

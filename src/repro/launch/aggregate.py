@@ -27,7 +27,7 @@ from collections import defaultdict
 from repro.configs import cells, get_config, get_shape
 from repro.configs.analysis import model_flops, param_counts
 from repro.configs.registry import segment_counts
-from repro.launch.roofline import HBM_BW, ICI_BW, PEAK_FLOPS, Roofline
+from repro.launch.roofline import Roofline
 
 METRICS = ("hlo_flops", "hlo_bytes", "collective_bytes_per_chip")
 

@@ -28,7 +28,7 @@ import numpy as np
 PEAKS = {
     "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
 }
-V5E = "TPU v5 lite"
+V5E = "TPU v5 lite"      # the dry-run model's target chip
 
 
 def peaks(device_kind: str) -> dict:
@@ -42,11 +42,6 @@ def peaks(device_kind: str) -> dict:
                        f"{device_kind!r}; add them to PEAKS "
                        f"(known: {sorted(PEAKS)})") from None
 
-
-# the dry-run model's target chip
-PEAK_FLOPS = PEAKS[V5E]["flops"]        # bf16 / chip
-HBM_BW = PEAKS[V5E]["hbm_bw"]           # bytes/s / chip
-ICI_BW = PEAKS[V5E]["ici_bw"]           # bytes/s / link
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -137,16 +132,17 @@ class Roofline:
     note: str = ""
 
     def finalize(self):
-        self.compute_s = self.hlo_flops / (self.chips * PEAK_FLOPS)
-        self.memory_s = self.hlo_bytes / (self.chips * HBM_BW)
-        self.collective_s = self.collective_bytes_per_chip / ICI_BW
+        chip = PEAKS[V5E]
+        self.compute_s = self.hlo_flops / (self.chips * chip["flops"])
+        self.memory_s = self.hlo_bytes / (self.chips * chip["hbm_bw"])
+        self.collective_s = self.collective_bytes_per_chip / chip["ici_bw"]
         terms = {"compute": self.compute_s, "memory": self.memory_s,
                  "collective": self.collective_s}
         self.dominant = max(terms, key=terms.get)
         self.useful_ratio = (self.model_flops / self.hlo_flops
                              if self.hlo_flops else 0.0)
         bound = max(self.compute_s, self.memory_s, self.collective_s)
-        ideal = self.model_flops / (self.chips * PEAK_FLOPS)
+        ideal = self.model_flops / (self.chips * chip["flops"])
         self.roofline_fraction = ideal / bound if bound > 0 else 0.0
         return self
 

@@ -26,7 +26,7 @@ import random
 from dataclasses import dataclass
 
 from repro.core.space import ParamSpace, axis
-from repro.launch.roofline import HBM_BW, PEAK_FLOPS
+from repro.launch.roofline import PEAKS, V5E
 
 # hardware model (the v5e peaks of launch/roofline.py) + a per-grid-cell
 # launch overhead term — the block knobs trade this overhead against
@@ -180,8 +180,9 @@ def predicted_cost_us(kernel: str, cell: dict) -> float:
         cells = b * kvh * w
     else:
         raise KeyError(f"unknown kernel {kernel!r} (have {sorted(SPECS)})")
-    return (flops / PEAK_FLOPS * 1e6
-            + (qo_bytes + kv_bytes) / HBM_BW * 1e6
+    chip = PEAKS[V5E]
+    return (flops / chip["flops"] * 1e6
+            + (qo_bytes + kv_bytes) / chip["hbm_bw"] * 1e6
             + cells * CELL_OVERHEAD_US)
 
 

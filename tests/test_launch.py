@@ -46,10 +46,9 @@ def test_compile_cache_defaults_to_checkout(monkeypatch):
 
 
 def test_peaks_unknown_device_kind_raises():
-    from repro.launch.roofline import HBM_BW, PEAK_FLOPS, peaks
+    from repro.launch.roofline import PEAKS, V5E, peaks
 
-    v5e = peaks("TPU v5 lite")
-    assert (v5e["flops"], v5e["hbm_bw"]) == (PEAK_FLOPS, HBM_BW)
+    assert peaks("TPU v5 lite") is PEAKS[V5E]
     with pytest.raises(KeyError, match="no published peaks"):
         peaks("cpu")
 

@@ -1,8 +1,7 @@
 """Roofline extraction: HLO collective parsing, term math, extrapolation."""
 import pytest
 
-from repro.launch.roofline import (Roofline, analyze, parse_collectives,
-                                   PEAK_FLOPS, HBM_BW, ICI_BW)
+from repro.launch.roofline import PEAKS, V5E, analyze, parse_collectives
 
 HLO = """
 HloModule test
@@ -37,10 +36,11 @@ def test_analyze_terms_and_dominant():
                 chips=256,
                 cost={"flops": 1e12, "bytes accessed": 1e9},
                 hlo_text=HLO, model_flops=200e12)
-    assert r.compute_s == pytest.approx(1e12 * 256 / (256 * PEAK_FLOPS))
-    assert r.memory_s == pytest.approx(1e9 * 256 / (256 * HBM_BW))
+    chip = PEAKS[V5E]
+    assert r.compute_s == pytest.approx(1e12 * 256 / (256 * chip["flops"]))
+    assert r.memory_s == pytest.approx(1e9 * 256 / (256 * chip["hbm_bw"]))
     assert r.collective_s == pytest.approx(
-        r.collective_bytes_per_chip / ICI_BW)
+        r.collective_bytes_per_chip / chip["ici_bw"])
     assert r.dominant == "compute"
     assert 0 < r.useful_ratio <= 1.0
     assert 0 < r.roofline_fraction <= 1.0

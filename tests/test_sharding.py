@@ -120,6 +120,7 @@ PER_SHARD = textwrap.dedent("""
 
     ks = jax.random.split(jax.random.PRNGKey(0), 8)
     n = lambda i, s: jax.random.normal(ks[i], s, jnp.float32)
+    table = jax.random.permutation(ks[6], 16).astype(jnp.int32).reshape(4, 4)
     calls = {
         "flash": (lambda q, k, v: ops.flash_attention(q, k, v),
                   (n(0, (4, 32, 4, 16)), n(1, (4, 32, 2, 16)),
@@ -128,6 +129,10 @@ PER_SHARD = textwrap.dedent("""
                        q, k, v, jnp.array([3, 9, 16, 30], jnp.int32)),
                    (n(3, (4, 4, 16)), n(4, (2, 4, 32, 16)),
                     n(5, (2, 4, 32, 16)))),
+        "paged": (lambda q, k, v: ops.decode_attention_paged(
+                      q, k, v, table, jnp.array([3, 9, 16, 30], jnp.int32)),
+                  (n(3, (4, 4, 16)), n(4, (2, 16, 8, 16)),
+                   n(5, (2, 16, 8, 16)))),
         "ssd": (lambda x, Bm, Cm: ops.ssd_scan(
                     x, jnp.full(x.shape[:3], 0.5), -jnp.ones(4), Bm, Cm,
                     chunk=16, return_final_state=True),
@@ -145,6 +150,17 @@ PER_SHARD = textwrap.dedent("""
                         jax.tree_util.tree_leaves(want), strict=True):
             np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                        atol=1e-5, rtol=1e-5, err_msg=name)
+    # the cache arrives sharded as models/attention.py declares it; with
+    # K dividing the model axis no device gathers another's heads
+    for name, logical in (("decode", ("kv_heads", "batch", "seq_kv", None)),
+                          ("paged", ("kv_heads", None, "seq_kv", None))):
+        fn, (q, k, v) = calls[name]
+        put = lambda x: jax.device_put(x, rules.sharding(logical, x.shape))
+        def sharded(*a, fn=fn):
+            with use_rules(rules):
+                return fn(*a)
+        hlo = jax.jit(sharded).lower(q, put(k), put(v)).compile().as_text()
+        assert "all-gather" not in hlo, name
     # the differentiable kernels take gradients per shard too
     q, k, v = calls["flash"][1]
     def loss(q, rules=None):
@@ -160,7 +176,8 @@ PER_SHARD = textwrap.dedent("""
 def test_kernels_run_per_shard_subprocess():
     """Under sharding rules every Pallas kernel runs per shard inside
     shard_map (a Mosaic call cannot be partitioned by XLA) and matches the
-    unsharded call."""
+    unsharded call; the decode kernels leave a head-sharded cache where
+    it is."""
     env = dict(os.environ, PYTHONPATH="src")
     r = subprocess.run([sys.executable, "-c", PER_SHARD],
                        capture_output=True, text=True, env=env, timeout=300,

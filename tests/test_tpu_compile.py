@@ -44,6 +44,19 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture(scope="module")
+def mesh_2x2(topo):
+    from repro.launch.mesh import make_mesh
+    return make_mesh((2, 2), ("data", "model"), devices=topo.devices)
+
+
+@pytest.fixture
+def kernel_dispatch(monkeypatch):
+    """Model code takes the Pallas kernels, as it does on a chip (the
+    backend of this process is the CPU)."""
+    monkeypatch.setenv("REPRO_PALLAS", "kernel")
+
+
 @pytest.fixture
 def no_compile_cache():
     """Compiles for a described chip are written to the persistent cache
@@ -114,3 +127,67 @@ def test_ssd_scan_compiles_mamba2_130m(with_h0, one_chip, no_compile_cache):
 
     hlo = _compiled_hlo(fn, one_chip, *shapes)
     assert "tpu_custom_call" in hlo
+
+
+def test_sharded_train_step_compiles_smollm_360m(mesh_2x2, kernel_dispatch,
+                                                 no_compile_cache):
+    """The ZeRO-1 train step that ``chip_smoke.py --four-chips`` runs, at
+    full width on the (data=2, model=2) mesh.  XLA cannot partition a
+    Mosaic call, so this compiles only if every kernel runs per shard."""
+    from repro.configs import ShapeConfig, get_config
+    from repro.launch.inputs import input_specs
+    from repro.sharding.rules import make_rules, use_rules
+    from repro.train.optimizer import get_optimizer
+    from repro.train.schedule import constant
+    from repro.train.train_step import make_train_step
+
+    cfg = get_config("smollm-360m")
+    rules = make_rules(mesh_2x2)
+    opt = get_optimizer("adamw")
+    step_fn = make_train_step(cfg, opt, constant(3e-4))
+    args = input_specs(cfg, ShapeConfig("train", MAX_SEQ, 4, "train"), rules,
+                       opt=opt, zero1=True)
+
+    def step(*a):
+        with use_rules(rules):
+            return step_fn(*a)
+
+    assert "tpu_custom_call" in jax.jit(step).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_sharded_decode_keeps_the_cache_local(layout, mesh_2x2,
+                                              kernel_dispatch,
+                                              no_compile_cache):
+    """qwen3-4b's 8 KV heads divide the model axis: each chip attends with
+    its own heads of the cache, and no collective gathers the cache."""
+    from repro.kernels import ops
+    from repro.sharding.rules import make_rules, use_rules
+
+    H, K, D = ATTN["qwen3-4b"]
+    rules = make_rules(mesh_2x2)
+
+    def sds(shape, dtype, logical):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=rules.sharding(logical, shape))
+
+    q = sds((SLOTS, H, D), jnp.bfloat16, ("batch", "heads", None))
+    kv_len = sds((SLOTS,), jnp.int32, ("batch",))
+    if layout == "dense":       # the layouts of models/attention.py
+        cache = sds((K, SLOTS, MAX_SEQ, D), jnp.bfloat16,
+                    ("kv_heads", "batch", "seq_kv", None))
+        fn, args = ops.decode_attention, (q, cache, cache, kv_len)
+    else:
+        W = MAX_SEQ // PAGE
+        pool = sds((K, SLOTS * W, PAGE, D), jnp.bfloat16,
+                   ("kv_heads", None, "seq_kv", None))
+        table = sds((SLOTS, W), jnp.int32, ("batch", None))
+        fn, args = ops.decode_attention_paged, (q, pool, pool, table, kv_len)
+
+    def decode(*a):
+        with use_rules(rules):
+            return fn(*a)
+
+    hlo = jax.jit(decode).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert "all-gather" not in hlo
