@@ -51,9 +51,10 @@ def _mode() -> str:
     return "ref"
 
 
-def _kernel_with_ref_vjp(kernel_fn, ref_fn, *args):
+def _kernel_with_ref_vjp(kernel_fn, ref_fn, *args, bwd_scope: str):
     """``kernel_fn(*args)``, with the gradient of ``ref_fn`` (same math,
-    plain XLA) as its VJP — the backward recomputes the reference."""
+    plain XLA) as its VJP — the backward recomputes the reference, under
+    the ``jax.named_scope`` ``bwd_scope``."""
     @jax.custom_vjp
     def f(*a):
         return kernel_fn(*a)
@@ -62,7 +63,8 @@ def _kernel_with_ref_vjp(kernel_fn, ref_fn, *args):
         return kernel_fn(*a), a
 
     def bwd(a, g):
-        return jax.vjp(ref_fn, *a)[1](g)
+        with jax.named_scope(bwd_scope):
+            return jax.vjp(ref_fn, *a)[1](g)
 
     f.defvjp(fwd, bwd)
     return f(*args)
@@ -172,7 +174,8 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
     def kernel(q, k, v):
         return _per_shard(call, (q, k, v), (spec,) * 3, spec)
 
-    return _kernel_with_ref_vjp(kernel, xla, q, k, v)
+    return _kernel_with_ref_vjp(kernel, xla, q, k, v,
+                                bwd_scope="attention_bwd")
 
 
 def decode_attention(q, k, v, kv_len, *, scale: float | None = None,
@@ -284,4 +287,5 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int | None = None, h0=None,
                           (rows, ("batch", None, None), (None,), rows, rows,
                            rows), out)
 
-    return _kernel_with_ref_vjp(kernel, reference, x, dt, A, Bm, Cm, h0)
+    return _kernel_with_ref_vjp(kernel, reference, x, dt, A, Bm, Cm, h0,
+                                bwd_scope="ssd_bwd")

@@ -78,9 +78,16 @@ def main(argv=None):
     steps = eng.run_until_drained()
     dt = time.time() - t0
     total = sum(len(r.output) for r in reqs)
+    st = eng.stats
+    pct = lambda part, whole: f"{100 * part / whole:.1f}%" if whole else "n/a"
     print(f"[launch.serve] {args.arch}: {args.requests} requests, "
           f"{total} tokens in {steps} steps / {dt:.1f}s "
-          f"({total/dt:.1f} tok/s, {args.slots} slots, {args.mode} mode)")
+          f"({total/dt:.1f} tok/s, {args.slots} slots, {args.mode} mode); "
+          f"prefill_row_use "
+          f"{pct(st['prefill_rows_active'], st['prefill_rows'])}, "
+          f"decode_row_use {pct(st['decode_rows_live'], st['decode_rows'])}, "
+          f"forced_decode_share "
+          f"{pct(st['decode_rows_forced'], st['decode_rows_live'])}")
     if args.kv_layout == "paged":
         ks = eng.kv_stats()
         print(f"[launch.serve] paged KV: {ks['num_pages']} pages x "

@@ -1,6 +1,10 @@
 """Residual blocks: (mixer ∈ {attn, mla, mamba}) + (ffn ∈ {dense, moe, none}),
 plus the Jamba super-block (hybrid interleave) and stacking helpers for
 jax.lax.scan over layer stacks.
+
+Each half of a block runs under a ``jax.named_scope`` (``MIXER_SCOPE``,
+``FFN_SCOPE``), so its operations carry the name in their HLO ``op_name``
+metadata and a device profile can attribute time to it.
 """
 from __future__ import annotations
 
@@ -16,6 +20,9 @@ from repro.models.layers import (apply_dense_ffn, make_dense_ffn, make_norm,
                                  rmsnorm)
 from repro.models.moe import apply_moe, make_moe
 from repro.models.params import Param, tree_map
+
+MIXER_SCOPE = {"attn": "attention", "mla": "mla", "mamba": "mamba"}
+FFN_SCOPE = {"dense": "mlp", "moe": "moe"}
 
 
 # ---------------------------------------------------------------------------
@@ -42,18 +49,12 @@ def make_block(cfg, mixer: str, ffn: str):
     return p
 
 
-def apply_block(cfg, p, h, positions, mixer: str, ffn: str):
-    """Full-sequence residual block. Returns (h, aux_loss)."""
-    x = rmsnorm(h, p["ln1"], cfg.norm_eps)
-    if mixer == "attn":
-        r, _ = attn_mod.apply_attention(cfg, p["mixer"], x, positions)
-    elif mixer == "mla":
-        r, _ = mla_mod.apply_mla(cfg, p["mixer"], x, positions)
-    else:
-        r, _ = mamba_mod.apply_mamba(cfg, p["mixer"], x, positions)
-    h = h + r
+def _apply_ffn(cfg, p, h, ffn: str):
+    """The block's feed-forward half on the residual stream: (h, aux)."""
     aux = jnp.zeros((), jnp.float32)
-    if ffn != "none":
+    if ffn == "none":
+        return h, aux
+    with jax.named_scope(FFN_SCOPE[ffn]):
         x = rmsnorm(h, p["ln2"], cfg.norm_eps)
         if ffn == "moe":
             B, S, d = x.shape
@@ -61,34 +62,38 @@ def apply_block(cfg, p, h, positions, mixer: str, ffn: str):
             y = y.reshape(B, S, d)
         else:
             y = apply_dense_ffn(cfg, p["ffn"], x)
-        h = h + y
-    return h, aux
+    return h + y, aux
+
+
+def apply_block(cfg, p, h, positions, mixer: str, ffn: str):
+    """Full-sequence residual block. Returns (h, aux_loss)."""
+    with jax.named_scope(MIXER_SCOPE[mixer]):
+        x = rmsnorm(h, p["ln1"], cfg.norm_eps)
+        if mixer == "attn":
+            r, _ = attn_mod.apply_attention(cfg, p["mixer"], x, positions)
+        elif mixer == "mla":
+            r, _ = mla_mod.apply_mla(cfg, p["mixer"], x, positions)
+        else:
+            r, _ = mamba_mod.apply_mamba(cfg, p["mixer"], x, positions)
+    return _apply_ffn(cfg, p, h + r, ffn)
 
 
 def apply_block_collect(cfg, p, h, positions, mixer: str, ffn: str):
     """Like apply_block but also returns the prefill cache
     (attn: {k,v}, mla: {ckv,kpe}, mamba: {conv,ssm})."""
-    x = rmsnorm(h, p["ln1"], cfg.norm_eps)
-    if mixer == "attn":
-        r, (k, v) = attn_mod.apply_attention(cfg, p["mixer"], x, positions)
-        cache = {"k": k, "v": v}
-    elif mixer == "mla":
-        r, (ckv, kpe) = mla_mod.apply_mla(cfg, p["mixer"], x, positions)
-        cache = {"ckv": ckv, "kpe": kpe}
-    else:
-        r, (conv, ssm) = mamba_mod.apply_mamba(cfg, p["mixer"], x, positions)
-        cache = {"conv": conv, "ssm": ssm}
-    h = h + r
-    aux = jnp.zeros((), jnp.float32)
-    if ffn != "none":
-        x = rmsnorm(h, p["ln2"], cfg.norm_eps)
-        if ffn == "moe":
-            Bs, S, d = x.shape
-            y, aux = apply_moe(cfg, p["ffn"], x.reshape(Bs * S, d))
-            y = y.reshape(Bs, S, d)
+    with jax.named_scope(MIXER_SCOPE[mixer]):
+        x = rmsnorm(h, p["ln1"], cfg.norm_eps)
+        if mixer == "attn":
+            r, (k, v) = attn_mod.apply_attention(cfg, p["mixer"], x, positions)
+            cache = {"k": k, "v": v}
+        elif mixer == "mla":
+            r, (ckv, kpe) = mla_mod.apply_mla(cfg, p["mixer"], x, positions)
+            cache = {"ckv": ckv, "kpe": kpe}
         else:
-            y = apply_dense_ffn(cfg, p["ffn"], x)
-        h = h + y
+            r, (conv, ssm) = mamba_mod.apply_mamba(cfg, p["mixer"], x,
+                                                   positions)
+            cache = {"conv": conv, "ssm": ssm}
+    h, aux = _apply_ffn(cfg, p, h + r, ffn)
     return h, aux, cache
 
 
@@ -118,34 +123,26 @@ def apply_block_decode(cfg, p, h, cache, pos, mixer: str, ffn: str,
     """One-token decode. ``page_table`` not None selects the paged cache
     layout for attention/MLA mixers (mamba state is dense either way).
     Returns (h, new_cache)."""
-    x = rmsnorm(h, p["ln1"], cfg.norm_eps)
-    if mixer == "attn":
-        r, new_cache = (attn_mod.apply_attention_decode_paged(
-                            cfg, p["mixer"], x, cache, pos, page_table,
-                            active)
-                        if page_table is not None
-                        else attn_mod.apply_attention_decode(
-                            cfg, p["mixer"], x, cache, pos, active))
-    elif mixer == "mla":
-        r, new_cache = (mla_mod.apply_mla_decode_paged(
-                            cfg, p["mixer"], x, cache, pos, page_table,
-                            active)
-                        if page_table is not None
-                        else mla_mod.apply_mla_decode(cfg, p["mixer"], x,
-                                                      cache, pos, active))
-    else:
-        r, new_cache = mamba_mod.apply_mamba_decode(cfg, p["mixer"], x, cache,
-                                                    pos, active)
-    h = h + r
-    if ffn != "none":
-        x = rmsnorm(h, p["ln2"], cfg.norm_eps)
-        if ffn == "moe":
-            B, S, d = x.shape
-            y, _ = apply_moe(cfg, p["ffn"], x.reshape(B * S, d))
-            y = y.reshape(B, S, d)
+    with jax.named_scope(MIXER_SCOPE[mixer]):
+        x = rmsnorm(h, p["ln1"], cfg.norm_eps)
+        if mixer == "attn":
+            r, new_cache = (attn_mod.apply_attention_decode_paged(
+                                cfg, p["mixer"], x, cache, pos, page_table,
+                                active)
+                            if page_table is not None
+                            else attn_mod.apply_attention_decode(
+                                cfg, p["mixer"], x, cache, pos, active))
+        elif mixer == "mla":
+            r, new_cache = (mla_mod.apply_mla_decode_paged(
+                                cfg, p["mixer"], x, cache, pos, page_table,
+                                active)
+                            if page_table is not None
+                            else mla_mod.apply_mla_decode(cfg, p["mixer"], x,
+                                                          cache, pos, active))
         else:
-            y = apply_dense_ffn(cfg, p["ffn"], x)
-        h = h + y
+            r, new_cache = mamba_mod.apply_mamba_decode(
+                cfg, p["mixer"], x, cache, pos, active)
+    h, _ = _apply_ffn(cfg, p, h + r, ffn)
     return h, new_cache
 
 
@@ -154,34 +151,26 @@ def apply_block_prefill_chunk(cfg, p, h, cache, start, mixer: str, ffn: str,
     """Chunked prefill through one block. h: [B, C, d]; start: [B] int32
     per-slot cache offset of the chunk; ``page_table`` not None selects
     the paged layout for attention/MLA. Returns (h, new_cache)."""
-    x = rmsnorm(h, p["ln1"], cfg.norm_eps)
-    if mixer == "attn":
-        r, new_cache = (attn_mod.apply_attention_prefill_chunk_paged(
-                            cfg, p["mixer"], x, cache, start, page_table,
-                            active)
-                        if page_table is not None
-                        else attn_mod.apply_attention_prefill_chunk(
-                            cfg, p["mixer"], x, cache, start, active))
-    elif mixer == "mla":
-        r, new_cache = (mla_mod.apply_mla_prefill_chunk_paged(
-                            cfg, p["mixer"], x, cache, start, page_table,
-                            active)
-                        if page_table is not None
-                        else mla_mod.apply_mla_prefill_chunk(
-                            cfg, p["mixer"], x, cache, start, active))
-    else:
-        r, new_cache = mamba_mod.apply_mamba_prefill_chunk(
-            cfg, p["mixer"], x, cache, start, active)
-    h = h + r
-    if ffn != "none":
-        x = rmsnorm(h, p["ln2"], cfg.norm_eps)
-        if ffn == "moe":
-            B, S, d = x.shape
-            y, _ = apply_moe(cfg, p["ffn"], x.reshape(B * S, d))
-            y = y.reshape(B, S, d)
+    with jax.named_scope(MIXER_SCOPE[mixer]):
+        x = rmsnorm(h, p["ln1"], cfg.norm_eps)
+        if mixer == "attn":
+            r, new_cache = (attn_mod.apply_attention_prefill_chunk_paged(
+                                cfg, p["mixer"], x, cache, start, page_table,
+                                active)
+                            if page_table is not None
+                            else attn_mod.apply_attention_prefill_chunk(
+                                cfg, p["mixer"], x, cache, start, active))
+        elif mixer == "mla":
+            r, new_cache = (mla_mod.apply_mla_prefill_chunk_paged(
+                                cfg, p["mixer"], x, cache, start, page_table,
+                                active)
+                            if page_table is not None
+                            else mla_mod.apply_mla_prefill_chunk(
+                                cfg, p["mixer"], x, cache, start, active))
         else:
-            y = apply_dense_ffn(cfg, p["ffn"], x)
-        h = h + y
+            r, new_cache = mamba_mod.apply_mamba_prefill_chunk(
+                cfg, p["mixer"], x, cache, start, active)
+    h, _ = _apply_ffn(cfg, p, h + r, ffn)
     return h, new_cache
 
 

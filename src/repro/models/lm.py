@@ -121,6 +121,7 @@ def head_weights(cfg, params):
     return params["lm_head"]
 
 
+@jax.named_scope("head")
 def apply_head(cfg, params, h):
     w = head_weights(cfg, params)
     if cfg.num_codebooks:
@@ -189,6 +190,7 @@ def _xent_chunk(cfg, params, h, targets, mask):
     return jnp.sum(nll * mf), jnp.sum(mf)
 
 
+@jax.named_scope("loss")
 def chunked_xent(cfg, params, h, targets, mask, chunk: int = 512):
     """Sequence-chunked xent: avoids materialising [B, S, V] logits."""
     import os as _os2

@@ -49,6 +49,15 @@ latency of resident slots stays flat.
 Malformed prompts (empty, or too long for ``max_seq``) are rejected with
 a typed failure (``Request.failed`` + ``fail_reason``) instead of
 crashing the engine; serving continues for everyone else.
+
+Under a running ``jax.profiler`` trace, ``step()`` records host spans
+named ``repro.serve.*``: ``step`` around the call, and inside it ``admit``,
+``prefill`` (one chunk call's host preparation and dispatch), and in fused
+mode ``decode_dispatch`` (state upload and the launch), ``decode_wait``
+(the blocking device-to-host transfers) and ``unpack`` (emissions and
+retirement).  ``prefill`` and ``unpack`` carry the per-call increments of
+the row counters in ``stats``.  With the profiler off a span costs one
+check; it adds no device work and no transfer.
 """
 from __future__ import annotations
 
@@ -68,6 +77,8 @@ from repro.serve.sampler import sample, sample_batch
 
 # paged-KV geometry served on a tune-cache miss (the pre-tuning default)
 _DEFAULT_PAGE_SIZE = 16
+
+_span = jax.profiler.TraceAnnotation
 
 
 def _resolve_page_size(cfg, batch_slots: int, max_seq: int) -> int:
@@ -332,8 +343,15 @@ class DecodeEngine:
         self.steps = 0
         self._root_key = jax.random.PRNGKey(rng_seed)
         self._admitted = 0
+        # row counters: prefill rows computed (B x C a chunk call) and of
+        # those the admitted slots' rows; decode rows computed (B a step),
+        # live (a slot decoding), forced (the sampled token replaced by the
+        # next prompt token) and emitted (forced + emitted = live)
         self.stats = {"admissions": 0, "rejected": 0, "preemptions": 0,
-                      "admit_cache_elems": 0, "peak_occupied": 0}
+                      "admit_cache_elems": 0, "peak_occupied": 0,
+                      "prefill_rows": 0, "prefill_rows_active": 0,
+                      "decode_rows": 0, "decode_rows_live": 0,
+                      "decode_rows_forced": 0, "decode_rows_emitted": 0}
 
         # slot-state leaves (SSM/conv — anything without a seq_kv axis)
         # must be zeroed when a slot is reused: position masking protects
@@ -580,18 +598,23 @@ class DecodeEngine:
             self._sync_page_table()
         if not take:
             return
-        tok = np.zeros((self.B, C, *self.tokens.shape[2:]), np.int32)
-        start = np.zeros((self.B,), np.int32)
-        active = np.zeros((self.B,), bool)
-        for s in take:
-            d = int(self.pf_done[s])
-            tok[s] = self.prompt_buf[s, d:d + C]
-            start[s] = d
-            active[s] = True
-        self.cache = _prefill_chunk(
-            self.cfg, self.params, self.cache, jnp.asarray(tok),
-            jnp.asarray(start), jnp.asarray(active),
-            self._pt_dev if self.pool is not None else None)
+        rows = {"prefill_rows": self.B * C,
+                "prefill_rows_active": len(take) * C}
+        for k, v in rows.items():
+            self.stats[k] += v
+        with _span("repro.serve.prefill", **rows):
+            tok = np.zeros((self.B, C, *self.tokens.shape[2:]), np.int32)
+            start = np.zeros((self.B,), np.int32)
+            active = np.zeros((self.B,), bool)
+            for s in take:
+                d = int(self.pf_done[s])
+                tok[s] = self.prompt_buf[s, d:d + C]
+                start[s] = d
+                active[s] = True
+            self.cache = _prefill_chunk(
+                self.cfg, self.params, self.cache, jnp.asarray(tok),
+                jnp.asarray(start), jnp.asarray(active),
+                self._pt_dev if self.pool is not None else None)
         for s in take:
             self.pf_done[s] += C
             if self.pf_done[s] >= self.pf_target[s]:
@@ -606,6 +629,17 @@ class DecodeEngine:
             self._pt_stale = True
 
     # ------------------------------------------------------------------
+    def _count_decode(self, steps: int, pos0, cursor0, emitted: int) -> dict:
+        """Add one sync's decode rows to ``stats`` from the host arrays
+        before and after it; returns the increments."""
+        rows = {"decode_rows": self.B * steps,
+                "decode_rows_live": int((self.pos - pos0).sum()),
+                "decode_rows_forced": int((self.cursor - cursor0).sum()),
+                "decode_rows_emitted": emitted}
+        for k, v in rows.items():
+            self.stats[k] += v
+        return rows
+
     def _host_step(self) -> int:
         """Seed-style per-step host sync (benchmark baseline)."""
         if not self.live.any():
@@ -614,6 +648,8 @@ class DecodeEngine:
             self._ensure_decode_pages(1)
         if not self.live.any():         # everyone preempted (tiny pool)
             return 0
+        pos0, cursor0 = self.pos.copy(), self.cursor.copy()
+        emitted = 0
         logits, self.cache = _decode_once(
             self.cfg, self.params, self.cache, jnp.asarray(self.tokens),
             jnp.asarray(self.pos), jnp.asarray(self.live),
@@ -641,12 +677,14 @@ class DecodeEngine:
                 jnp.asarray(logits_np[slot]), sub,
                 temperature=jnp.float32(req.temperature), top_k=req.top_k))
             req.output.append(np.array(tok))
+            emitted += 1
             self.remaining[slot] -= 1
             self.tokens[slot, 0] = tok
             if self.remaining[slot] <= 0 or self.pos[slot] >= self.max_seq - 1:
                 self.live[slot] = False
                 self._retire(slot)
                 finished += 1
+        self._count_decode(1, pos0, cursor0, emitted)
         return finished
 
     def _fused_sync(self) -> int:
@@ -657,46 +695,55 @@ class DecodeEngine:
             self._ensure_decode_pages(self.steps_per_sync)
         if not self.live.any():         # everyone preempted (tiny pool)
             return 0
-        state = {"tokens": jnp.asarray(self.tokens),
-                 "pos": jnp.asarray(self.pos),
-                 "cursor": jnp.asarray(self.cursor),
-                 "plen": jnp.asarray(self.plen),
-                 "remaining": jnp.asarray(self.remaining),
-                 "live": jnp.asarray(self.live),
-                 "keys": jnp.asarray(self.keys)}
-        self.cache, state, sampled, emit = _fused_steps(
-            self.cfg, self.steps_per_sync, self.params, self.cache, state,
-            jnp.asarray(self.prompt_buf), jnp.asarray(self.temp),
-            jnp.asarray(self.topk),
-            self._pt_dev if self.pool is not None else None,
-            self._paged_meta)
+        pos0, cursor0 = self.pos, self.cursor
+        with _span("repro.serve.decode_dispatch"):
+            state = {"tokens": jnp.asarray(self.tokens),
+                     "pos": jnp.asarray(self.pos),
+                     "cursor": jnp.asarray(self.cursor),
+                     "plen": jnp.asarray(self.plen),
+                     "remaining": jnp.asarray(self.remaining),
+                     "live": jnp.asarray(self.live),
+                     "keys": jnp.asarray(self.keys)}
+            self.cache, state, sampled, emit = _fused_steps(
+                self.cfg, self.steps_per_sync, self.params, self.cache, state,
+                jnp.asarray(self.prompt_buf), jnp.asarray(self.temp),
+                jnp.asarray(self.topk),
+                self._pt_dev if self.pool is not None else None,
+                self._paged_meta)
         self.steps += self.steps_per_sync
-        sampled = np.asarray(sampled)
-        emit = np.asarray(emit)
-        for s in range(self.steps_per_sync):
-            for slot in np.nonzero(emit[s])[0]:
-                self.slot_req[slot].output.append(np.array(sampled[s, slot]))
-        self.tokens = np.array(state["tokens"])
-        self.pos = np.array(state["pos"])
-        self.cursor = np.array(state["cursor"])
-        self.remaining = np.array(state["remaining"])
-        self.keys = np.array(state["keys"])
-        new_live = np.array(state["live"])
-        finished = 0
-        for slot in np.nonzero(self.live & ~new_live)[0]:
-            self._retire(slot)
-            finished += 1
-        self.live = new_live
+        with _span("repro.serve.decode_wait"):
+            sampled = np.asarray(sampled)
+            emit = np.asarray(emit)
+            self.tokens = np.array(state["tokens"])
+            self.pos = np.array(state["pos"])
+            self.cursor = np.array(state["cursor"])
+            self.remaining = np.array(state["remaining"])
+            self.keys = np.array(state["keys"])
+            new_live = np.array(state["live"])
+        rows = self._count_decode(self.steps_per_sync, pos0, cursor0,
+                                  int(emit.sum()))
+        with _span("repro.serve.unpack", **rows):
+            for s in range(self.steps_per_sync):
+                for slot in np.nonzero(emit[s])[0]:
+                    self.slot_req[slot].output.append(
+                        np.array(sampled[s, slot]))
+            finished = 0
+            for slot in np.nonzero(self.live & ~new_live)[0]:
+                self._retire(slot)
+                finished += 1
+            self.live = new_live
         return finished
 
     def step(self) -> int:
         """Admission + one stepping round; returns #requests finished.
 
         In fused mode one round is ``steps_per_sync`` decode steps."""
-        self._admit()
-        self._pump_prefill()
-        return self._fused_sync() if self.mode == "fused" \
-            else self._host_step()
+        with _span("repro.serve.step"):
+            with _span("repro.serve.admit"):
+                self._admit()
+            self._pump_prefill()
+            return self._fused_sync() if self.mode == "fused" \
+                else self._host_step()
 
     def run_until_drained(self, max_steps: int = 100_000) -> int:
         while (self.queue or any(r is not None for r in self.slot_req)) \

@@ -26,6 +26,7 @@ def _greedy(lf):
     return jnp.min(jnp.where(lf == m, idx, v), axis=-1).astype(jnp.int32)
 
 
+@jax.named_scope("sampler")
 def sample(logits, rng, *, temperature=0.0, top_k: int = 0):
     """logits [..., V] -> token ids [...].
 
@@ -44,6 +45,7 @@ def sample(logits, rng, *, temperature=0.0, top_k: int = 0):
     return jnp.where(temp > 0.0, drawn, greedy)
 
 
+@jax.named_scope("sampler")
 def sample_batch(logits, keys, temperature, top_k):
     """Per-slot batched sampling for the serving engine.
 

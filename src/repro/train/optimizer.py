@@ -46,6 +46,7 @@ class AdamW:
             "count": jnp.zeros((), jnp.int32),
         }
 
+    @jax.named_scope("adamw")
     def update(self, grads, state, params, lr):
         c = state["count"] + 1
         b1c = 1 - self.b1 ** c.astype(jnp.float32)

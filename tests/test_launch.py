@@ -60,3 +60,27 @@ def test_make_mesh_builds_auto_axes():
 
     mesh = make_mesh((1, 1), ("data", "model"))
     assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+
+
+@pytest.mark.parametrize("layout", [[], ["--kv-layout", "paged",
+                                         "--page-size", "8",
+                                         "--num-pages", "40"]],
+                         ids=["dense", "paged"])
+def test_serve_summary_reports_row_use(layout, tmp_path):
+    """The serving launcher's summary line gives the engine's row shares,
+    so an operator sees them without a profiler."""
+    import re
+
+    path = os.pathsep.join(filter(None, [os.path.join(ROOT, "src"),
+                                         os.environ.get("PYTHONPATH")]))
+    r = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--arch", "smollm-360m",
+         "--requests", "6", "--prefill-chunk", "4", *layout],
+        cwd=ROOT, capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=path,
+                 JAX_COMPILATION_CACHE_DIR=str(tmp_path)))
+    assert r.returncode == 0, r.stderr
+    share = r"(\d+\.\d%|n/a)"
+    assert re.search(rf"tok/s.*; prefill_row_use {share}, decode_row_use "
+                     rf"{share}, forced_decode_share {share}$", r.stdout,
+                     re.M), r.stdout

@@ -324,3 +324,49 @@ def test_musicgen_codebook_outputs():
     eng.run_until_drained()
     assert len(r.output) == 2
     assert r.output[0].shape == (cfg.num_codebooks,)
+
+
+ROW_KEYS = ("prefill_rows", "prefill_rows_active", "decode_rows",
+            "decode_rows_live", "decode_rows_forced", "decode_rows_emitted")
+
+
+def _rows(eng) -> dict:
+    return {k: eng.stats[k] for k in ROW_KEYS}
+
+
+def test_row_counters_one_request():
+    """A 10-token prompt with 4-token chunks: two chunk calls compute 4 slots
+    x 4 rows each, one slot's rows active; position 8 is forced decode
+    (prompt token 9 replaces the sample), then 5 tokens are emitted, over
+    two syncs of 4 slots x 4 steps."""
+    cfg, eng = _engine("smollm-360m", slots=4, max_seq=64, prefill_chunk=4,
+                       steps_per_sync=4)
+    eng.submit(Request(prompt=np.arange(10, dtype=np.int32) + 1,
+                       max_new_tokens=5))
+    eng.run_until_drained()
+    assert _rows(eng) == {"prefill_rows": 32, "prefill_rows_active": 8,
+                          "decode_rows": 32, "decode_rows_live": 6,
+                          "decode_rows_forced": 1, "decode_rows_emitted": 5}
+
+
+@pytest.mark.parametrize("mode", ["fused", "host"])
+@pytest.mark.parametrize("kv_layout", ["dense", "paged"])
+def test_row_counters_known_counts(mode, kv_layout):
+    """Prompts of 3, 7, 10 and 13 tokens prefill 0, 4, 8 and 12 of them in
+    4-token chunks: three chunk calls of 16 rows (12, 8 and 4 active), and
+    2, 2, 1 and 0 prompt tokens go through forced decode."""
+    kw = {"page_size": 8} if kv_layout == "paged" else {}
+    cfg, eng = _engine("smollm-360m", slots=4, max_seq=64, prefill_chunk=4,
+                       steps_per_sync=4, mode=mode, kv_layout=kv_layout, **kw)
+    reqs = [Request(prompt=np.arange(L, dtype=np.int32) + 1, max_new_tokens=n)
+            for L, n in ((3, 2), (7, 3), (10, 5), (13, 4))]
+    for r in reqs:
+        eng.submit(r)
+    eng.run_until_drained()
+    rows = _rows(eng)
+    assert rows == {"prefill_rows": 48, "prefill_rows_active": 24,
+                    "decode_rows": 4 * eng.steps, "decode_rows_live": 19,
+                    "decode_rows_forced": 5, "decode_rows_emitted": 14}
+    assert rows["decode_rows_forced"] + rows["decode_rows_emitted"] \
+        == rows["decode_rows_live"]
+    assert sum(len(r.output) for r in reqs) == rows["decode_rows_emitted"]

@@ -11,6 +11,8 @@ The topology is described inside a module-scoped fixture, never at import
 time: only one process at a time may load the TPU library, and every
 pytest-xdist worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -20,6 +22,7 @@ from repro.kernels.decode_attention import (decode_attention,
                                             decode_attention_paged)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_scan
+from repro.models import lm
 
 # (heads, kv_heads, head_dim) at published widths
 ATTN = {"smollm-360m": (15, 5, 64), "qwen3-4b": (32, 8, 128)}
@@ -68,6 +71,16 @@ def no_compile_cache():
     yield
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
+
+
+def _op_names(hlo: str) -> list[str]:
+    return re.findall(r'op_name="([^"]*)"', hlo)
+
+
+def _in_scope(op_name: str, scope: str) -> bool:
+    """Whether ``scope`` is a component of the name stack ``op_name``; a
+    transformation wraps it, as in ``transpose(jvp(loss))``."""
+    return re.search(rf"(^|[/(]){scope}([/)]|$)", op_name) is not None
 
 
 def _compiled_hlo(fn, sharding, *shapes):
@@ -191,3 +204,61 @@ def test_sharded_decode_keeps_the_cache_local(layout, mesh_2x2,
     hlo = jax.jit(decode).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
     assert "all-gather" not in hlo
+
+
+def test_train_step_names_the_attention_backward(one_chip, kernel_dispatch,
+                                                 no_compile_cache):
+    """The chip's train step runs the flash kernel forward and the XLA
+    reference backward; the backward's operations carry ``attention_bwd``
+    in their ``op_name`` metadata, so a device profile can attribute them,
+    and the block scopes are there too.  The jitted program keeps its
+    module name."""
+    from repro.configs import reduced_config
+    from repro.train.optimizer import AdamW
+    from repro.train.schedule import constant
+    from repro.train.train_step import make_train_step
+
+    cfg = reduced_config("smollm-360m")
+    opt = AdamW()
+    params = jax.eval_shape(lambda: lm.init_lm(cfg, jax.random.PRNGKey(0)))
+    opt_state = jax.eval_shape(opt.init, params)
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+    step = make_train_step(cfg, opt, constant(3e-4))
+    hlo = jax.jit(step).lower(
+        on_chip(params), on_chip(opt_state),
+        {"tokens": jax.ShapeDtypeStruct((2, 256), jnp.int32,
+                                        sharding=one_chip)},
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ).compile().as_text()
+    assert hlo.startswith("HloModule jit_train_step")
+    assert "tpu_custom_call" in hlo
+    op_names = _op_names(hlo)
+    bwd = [n for n in op_names if _in_scope(n, "attention_bwd")]
+    assert bwd and all(_in_scope(n, "attention") for n in bwd)
+    for scope in ("mlp", "loss", "head", "adamw"):
+        assert any(_in_scope(n, scope) for n in op_names), scope
+
+
+def test_serving_programs_keep_their_module_names():
+    """Profile readers find the engine's programs by module name."""
+    from repro.configs import reduced_config
+    from repro.serve.engine import DecodeEngine, _fused_steps, _prefill_chunk
+
+    cfg = reduced_config("smollm-360m")
+    eng = DecodeEngine(cfg, lm.init_lm(cfg, jax.random.PRNGKey(0)),
+                       batch_slots=2, max_seq=32, prefill_chunk=8)
+    state = {k: jnp.asarray(getattr(eng, k)) for k in
+             ("tokens", "pos", "cursor", "plen", "remaining", "live", "keys")}
+    fused = _fused_steps.lower(
+        cfg, 2, eng.params, eng.cache, state, jnp.asarray(eng.prompt_buf),
+        jnp.asarray(eng.temp), jnp.asarray(eng.topk), None, None)
+    chunk = _prefill_chunk.lower(
+        cfg, eng.params, eng.cache, jnp.zeros((2, 8), jnp.int32),
+        jnp.zeros((2,), jnp.int32), jnp.ones((2,), bool), None)
+    assert fused.as_text().startswith("module @jit__fused_steps")
+    assert chunk.as_text().startswith("module @jit__prefill_chunk")
+    op_names = _op_names(fused.compile().as_text())
+    for scope in ("attention", "mlp", "head", "sampler"):
+        assert any(_in_scope(n, scope) for n in op_names), scope
