@@ -18,10 +18,14 @@ query token.  This kernel is shaped for the serving fast path instead:
 
 K/V are read in the cache's own kv-head-major layout, [K, B, Sk, D], so
 every (1, 1, block_k, D) block ends in (block_k, D) as Mosaic's tiling
-needs, and the decode step never transposes the cache.  Non-dividing Sk
-is handled by zero-padding K/V up to a block multiple in the wrapper;
-the pad region sits beyond every ``kv_len`` so the masking covers it.  The grid divisibility is asserted after padding (expolint
-pallas-rules).
+needs, and the decode step never transposes the cache.  The cache may
+be the whole layer stack, [L, K, B, Sk, D]: the layer to read rides the
+scalar-prefetch operand after the ``kv_len``s and steers the K/V index
+maps, so a decode step that carries the stacked cache through its layer
+loop never slices a layer out of it.  Non-dividing Sk is handled by
+zero-padding one layer's K/V up to a block multiple in the wrapper; the
+pad region sits beyond every ``kv_len`` so the masking covers it.  The
+grid divisibility is asserted after padding (expolint pallas-rules).
 
 ``decode_attention_paged`` is the same online-softmax loop over a *paged*
 KV pool: K/V live as [K, num_pages, page_size, D] pages shared by all
@@ -81,13 +85,13 @@ def _finalize(o_ref, l_scr, acc_scr):
     o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+def _kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             scale: float, block_k: int):
     b = pl.program_id(0)
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
     pl.when(ik == 0)(lambda: _init(m_scr, l_scr, acc_scr))
-    kv_len = len_ref[b]
+    kv_len = meta_ref[b]
     k_start = ik * block_k
     pl.when(k_start < kv_len)(lambda: _online_softmax_step(
         q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, scale=scale,
@@ -101,13 +105,17 @@ def _scratch(G: int, Dv: int):
             pltpu.VMEM((G, Dv), jnp.float32)]
 
 
-def decode_attention(q, k, v, kv_len, *, scale: float | None = None,
-                     block_k: int = 128, interpret: bool = False):
-    """q: [B, H, D]; k: [K, B, Sk, D]; v: [K, B, Sk, Dv]; kv_len: [B] int32
-    (per-slot live cache length, position p attended iff p < kv_len).
-    Returns [B, H, Dv]."""
+def decode_attention(q, k, v, kv_len, layer=0, *,
+                     scale: float | None = None, block_k: int = 128,
+                     interpret: bool = False):
+    """q: [B, H, D]; k: [L, K, B, Sk, D] (or one layer's [K, B, Sk, D]);
+    v: the same with Dv; kv_len: [B] int32 (per-slot live cache length,
+    position p attended iff p < kv_len); layer: int32 scalar, the layer
+    of a stacked k/v to attend to.  Returns [B, H, Dv]."""
+    if k.ndim == 4:
+        k, v = k[None], v[None]
     Bsz, H, D = q.shape
-    K, Sk = k.shape[0], k.shape[2]
+    K, Sk = k.shape[1], k.shape[3]
     Dv = v.shape[-1]
     assert H % K == 0, (H, K)
     G = H // K
@@ -116,28 +124,36 @@ def decode_attention(q, k, v, kv_len, *, scale: float | None = None,
     pad = -Sk % block_k
     if pad:
         # padded tail sits at kpos >= Sk >= every kv_len -> fully masked
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        widths = ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0))
+        k = jnp.pad(jax.lax.dynamic_index_in_dim(k, layer, 0), widths)
+        v = jnp.pad(jax.lax.dynamic_index_in_dim(v, layer, 0), widths)
+        layer = 0
     Skp = Sk + pad
     assert Skp % block_k == 0, (Skp, block_k)
     grid = (Bsz, K, Skp // block_k)
 
     qg = q.reshape(Bsz, K, G, D)
-    lens = jnp.asarray(kv_len, jnp.int32)
+    # kv_len [B] then the layer: one operand, so the call keeps its four
+    meta = jnp.concatenate([jnp.asarray(kv_len, jnp.int32).reshape(Bsz),
+                            jnp.asarray(layer, jnp.int32).reshape(1)])
     kernel = functools.partial(_kernel, scale=scale, block_k=block_k)
 
+    # a block past the slot's live rows maps to its last live one, so the
+    # pipeline fetches nothing for it (pl.when skips its compute)
+    kv_block = lambda b, h, ik, meta: (
+        meta[Bsz], h, b,
+        jnp.minimum(ik, jnp.maximum(meta[b] - 1, 0) // block_k), 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,      # kv_len lands in SMEM
+        num_scalar_prefetch=1,      # kv_len and the layer land in SMEM
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ik, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, ik, lens: (h, b, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, Dv),
-                         lambda b, h, ik, lens: (h, b, ik, 0)),
+            pl.BlockSpec((1, 1, G, D), lambda b, h, ik, meta: (b, h, 0, 0)),
+            pl.BlockSpec((None, 1, 1, block_k, D), kv_block),
+            pl.BlockSpec((None, 1, 1, block_k, Dv), kv_block),
         ],
         out_specs=pl.BlockSpec((1, 1, G, Dv),
-                               lambda b, h, ik, lens: (b, h, 0, 0)),
+                               lambda b, h, ik, meta: (b, h, 0, 0)),
         scratch_shapes=_scratch(G, Dv),
     )
     out = pl.pallas_call(
@@ -148,7 +164,7 @@ def decode_attention(q, k, v, kv_len, *, scale: float | None = None,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(lens, qg, k, v)
+    )(meta, qg, k, v)
     return out.reshape(Bsz, H, Dv)
 
 

@@ -29,6 +29,7 @@ from __future__ import annotations
 import os
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.sharding.rules import current_rules
@@ -38,7 +39,7 @@ from repro.tune import cache as _tune_cache
 # repro.tune.space.SPECS[*].defaults (the tuner's incumbents)
 _DEFAULT_BLOCK_Q = 128
 _DEFAULT_BLOCK_K = 128
-_DEFAULT_DECODE_BLOCK_K = 128
+_DEFAULT_DECODE_BLOCK_K = 512
 _DEFAULT_CHUNK = 64
 
 
@@ -178,41 +179,53 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
                                 bwd_scope="attention_bwd")
 
 
-def decode_attention(q, k, v, kv_len, *, scale: float | None = None,
-                     block_k: int | None = None):
+def _decode_block_k(sk: int) -> int:
+    """The default decode block: the largest of 512, 256 and 128 rows that
+    divides ``sk``, else 128 (the wrapper pads).  The kernel streams each
+    live block of the cache from HBM, and fewer, larger blocks keep the
+    DMA busy; one that divides spares the wrapper its pad, a copy of the
+    layer."""
+    return next((b for b in (_DEFAULT_DECODE_BLOCK_K, 256) if sk % b == 0),
+                128)
+
+
+def decode_attention(q, k, v, kv_len, layer=0, *,
+                     scale: float | None = None, block_k: int | None = None):
     """Sq=1 GQA decode attention over a ragged KV cache.
 
-    q: [B,H,D], k/v: [K,B,Sk,D/Dv] (the kv-head-major cache layout),
-    kv_len: [B] int32 -> [B,H,Dv].  Same dispatch policy as
-    ``flash_attention``: the pure-jnp reference on non-TPU backends, the
-    Pallas decode kernel
+    q: [B,H,D], k/v: [K,B,Sk,D/Dv] (the kv-head-major cache layout) or the
+    stacked [L,K,B,Sk,D/Dv] of every layer, kv_len: [B] int32, layer:
+    int32 scalar, the layer of a stacked k/v to read -> [B,H,Dv].  Same
+    dispatch policy as ``flash_attention``: the pure-jnp reference on
+    non-TPU backends, the Pallas decode kernel
     (``kernels/decode_attention.py``) runs on TPU or under
-    ``REPRO_PALLAS=interpret``.  ``block_k``: explicit > tuned > 128
-    (the wrapper zero-pads Sk, so any positive tuned value is valid)."""
+    ``REPRO_PALLAS=interpret``.  ``block_k``: explicit > tuned >
+    ``_decode_block_k`` (the wrapper zero-pads Sk, so any positive tuned
+    value is valid)."""
     mode = _mode()
     if mode in ("ref", "naive"):
-        return ref.decode_attention_ref(q, k, v, kv_len, scale=scale)
+        return ref.decode_attention_ref(q, k, v, kv_len, layer, scale=scale)
     Bsz, H, D = q.shape
-    K, Sk = k.shape[0], k.shape[2]
+    K, Sk = k.shape[-4], k.shape[-2]
     if block_k is None:
         cfg = _tuned("decode_attention",
                      {"b": Bsz, "sk": Sk, "h": H, "kvh": K, "d": D},
                      q.dtype)
-        block_k = int(cfg.get("block_k", _DEFAULT_DECODE_BLOCK_K))
+        block_k = int(cfg.get("block_k", 0))
         if block_k <= 0:
-            block_k = _DEFAULT_DECODE_BLOCK_K
+            block_k = _decode_block_k(Sk)
     from repro.kernels import decode_attention as dk
 
-    def call(q, k, v, kv_len):
-        return dk.decode_attention(q, k, v, kv_len, scale=scale,
+    def call(q, k, v, kv_len, layer):
+        return dk.decode_attention(q, k, v, kv_len, layer, scale=scale,
                                    block_k=block_k,
                                    interpret=(mode == "interpret"))
 
     # split heads the way the cache is stored, so it is never gathered
     h = _head_axis(H, K)
-    cache = (h, "batch", None, None)
-    return _per_shard(call, (q, k, v, kv_len),
-                      (("batch", h, None), cache, cache, ("batch",)),
+    cache = (None,) * (k.ndim - 4) + (h, "batch", None, None)
+    return _per_shard(call, (q, k, v, kv_len, jnp.asarray(layer, jnp.int32)),
+                      (("batch", h, None), cache, cache, ("batch",), ()),
                       ("batch", h, None))
 
 

@@ -44,9 +44,10 @@ def attention_ref(
 
 def decode_attention_ref(
     q: jax.Array,       # [B, H, D]
-    k: jax.Array,       # [K, B, Sk, D]
-    v: jax.Array,       # [K, B, Sk, Dv]
+    k: jax.Array,       # [K, B, Sk, D], or stacked [L, K, B, Sk, D]
+    v: jax.Array,       # [K, B, Sk, Dv], or stacked [L, K, B, Sk, Dv]
     kv_len: jax.Array,  # [B] int32 — position p attended iff p < kv_len
+    layer=0,            # int32 scalar: the layer of a stacked k/v
     *,
     scale: float | None = None,
 ) -> jax.Array:
@@ -56,6 +57,9 @@ def decode_attention_ref(
     matches ``kernels/decode_attention.py``.  Every slot must have
     ``kv_len >= 1`` (an all-masked row would softmax to NaN).
     Returns [B, H, Dv]."""
+    if k.ndim == 5:
+        k = jax.lax.dynamic_index_in_dim(k, layer, 0, keepdims=False)
+        v = jax.lax.dynamic_index_in_dim(v, layer, 0, keepdims=False)
     B, H, D = q.shape
     K, Sk = k.shape[0], k.shape[2]
     G = H // K

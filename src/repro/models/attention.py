@@ -137,6 +137,31 @@ def paged_write_kv(pool, page_table, positions, values, active=None):
         pool, page_table, positions, values, active)
 
 
+def _chunk_attention(cfg, q, k, v, positions):
+    """A chunk's queries against a slot's cache rows, fp32 softmax.
+
+    q: [B, C, H, hd]; k, v: [K, B, Smax, hd] (a dense layer or a page
+    table's gathered view); positions: [B, C], query i of slot b sees
+    cache row p iff p <= positions[b, i].  The dots batch over (kv head,
+    slot), the cache's own leading axes, so they read it as it lies.
+    Returns [B, C, H * hd] fp32."""
+    B, C = positions.shape
+    K, smax, hd = k.shape[0], k.shape[2], k.shape[3]
+    G = cfg.num_heads // K
+    qg = q.reshape(B, C, K, G, hd).transpose(2, 0, 3, 1, 4)  # [K,B,G,C,hd]
+    qg = qg.reshape(K, B, G * C, hd).astype(jnp.float32)
+    scores = jax.lax.dot_general(qg, k.astype(jnp.float32),
+                                 (((3,), (3,)), ((0, 1), (0, 1))))
+    scores = scores.reshape(K, B, G, C, smax) * (hd ** -0.5)
+    mask = jnp.arange(smax)[None, None, :] <= positions[:, :, None]
+    scores = jnp.where(mask[None, :, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).reshape(K, B, G * C, smax)
+    out = jax.lax.dot_general(probs, v.astype(jnp.float32),
+                              (((3,), (2,)), ((0, 1), (0, 1))))
+    out = out.reshape(K, B, G, C, hd).transpose(1, 3, 0, 2, 4)
+    return out.reshape(B, C, cfg.q_dim)
+
+
 def apply_attention_decode_paged(cfg, p, x, cache, pos, page_table,
                                  active=None):
     """One-token decode against the paged pool.  x: [B, 1, d]; cache:
@@ -169,30 +194,65 @@ def apply_attention_prefill_chunk_paged(cfg, p, x, cache, start, page_table,
     q, k_new, v_new = _qkv(cfg, p, x, positions)
     k = paged_write_kv(cache["k"], page_table, positions, k_new, active)
     v = paged_write_kv(cache["v"], page_table, positions, v_new, active)
-    kg = gather_kv_pages(k, page_table)                # [K, B, W*ps, hd]
-    vg = gather_kv_pages(v, page_table)
-    smax = kg.shape[2]
-    K = kg.shape[0]
-    G = cfg.num_heads // K
-    qg = q.reshape(B, C, K, G, cfg.head_dim).astype(jnp.float32)
-    scores = jnp.einsum("bqkgd,kbsd->bkgqs", qg, kg.astype(jnp.float32))
-    scores = scores * (cfg.head_dim ** -0.5)
-    mask = jnp.arange(smax)[None, None, :] <= positions[:, :, None]
-    scores = jnp.where(mask[:, None, None, :, :], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgqs,kbsd->bqkgd", probs, vg.astype(jnp.float32))
-    out = out.reshape(B, C, cfg.q_dim).astype(x.dtype)
-    return out @ p["wo"], {"k": k, "v": v}
+    out = _chunk_attention(cfg, q, gather_kv_pages(k, page_table),
+                           gather_kv_pages(v, page_table), positions)
+    return out.astype(x.dtype) @ p["wo"], {"k": k, "v": v}
 
 
-def apply_attention_prefill_chunk(cfg, p, x, cache, start, active=None):
+def _write_rows(buf, rows, layer, start, active):
+    """Write each slot's new rows into the cache buffer, in place.
+
+    buf: [K, B, Smax, hd], or with ``layer`` (an int32 scalar) the stack
+    [L, K, B, Smax, hd] written at that layer; rows: [K, B, C, hd], slot
+    b's rows for positions start[b] .. start[b]+C-1; active: optional [B]
+    bool.  Rows of an inactive slot, and rows at or past Smax, are
+    dropped.  Both forms keep the buffer in its own layout, so XLA updates
+    it where it lies:
+
+    * one row a slot (decode): one scatter whose window is one head_dim
+      row, its indices naming layer, KV head, slot and position.  With
+      the KV heads in the window XLA lays a carried stack out slot-major
+      and copies it whole to feed the kernel.
+    * a chunk: one ``dynamic_update_slice`` of the slot's [K, C, hd]
+      block per slot.  A scatter costs the chip per index, K·B·C of them
+      (37 of a 134 ms chunk at qwen3-4b's widths on a TPU v5e).  The
+      update clamps its start, so a block that would reach past Smax
+      lands lower and its rows before ``start`` write back what they
+      read."""
+    at = () if layer is None else (layer,)
+    K, B, smax, hd = buf.shape[-4:]
+    C = rows.shape[2]
+    if C == 1:
+        pos = start[:, None] if active is None else jnp.where(
+            active[:, None], start[:, None], smax)
+        return buf.at[(*at, jnp.arange(K)[:, None, None],
+                       jnp.arange(B)[None, :, None], pos[None])].set(
+                           rows, mode="drop")
+    lo = jnp.clip(start, 0, smax - C)
+    size = (1,) * len(at) + (K, 1, C, hd)
+    for b in range(B):
+        idx = (*at, 0, b, lo[b], 0)
+        old = jax.lax.dynamic_slice(buf, idx, size).reshape(K, C, hd)
+        src = jnp.arange(C) - (start[b] - lo[b])    # row of ``rows`` here
+        new = jnp.take(rows[:, b], jnp.clip(src, 0, C - 1), axis=1)
+        ok = src >= 0 if active is None else active[b] & (src >= 0)
+        blk = jnp.where(ok[None, :, None], new, old)
+        buf = jax.lax.dynamic_update_slice(buf, blk.reshape(size), idx)
+    return buf
+
+
+def apply_attention_prefill_chunk(cfg, p, x, cache, start, active=None,
+                                  layer=None):
     """Batched prefill of a C-token chunk into the KV cache.
 
-    x: [B, C, d]; cache: {k,v: [K, B, Smax, hd]}; start: [B] int32 (cache
+    x: [B, C, d]; cache: {k,v: [K, B, Smax, hd]}, or with ``layer`` (an
+    int32 scalar) the whole stack {k,v: [L, K, B, Smax, hd]}, of which
+    layer ``layer`` is written and read in place; start: [B] int32 (cache
     position of the chunk's first token — per-slot, so freshly admitted
     requests prefill while resident slots sit at different fill levels);
     active: optional [B] bool — inactive slots leave the cache untouched
-    and their outputs are garbage (callers must ignore them).
+    and their outputs are garbage (callers must ignore them).  Rows at or
+    past Smax are dropped.
 
     This is ``flash_attention(q_offset=...)`` generalised to a *traced
     per-slot* offset vector: chunk queries attend to the full cache with a
@@ -200,44 +260,35 @@ def apply_attention_prefill_chunk(cfg, p, x, cache, start, active=None):
     B, C, _ = x.shape
     positions = start[:, None] + jnp.arange(C)[None, :]         # [B, C]
     q, k_new, v_new = _qkv(cfg, p, x, positions)
-    smax = cache["k"].shape[2]
-    wpos = positions if active is None else jnp.where(
-        active[:, None], positions, smax)
-    b_idx = jnp.arange(B)[:, None]
-    k = cache["k"].at[:, b_idx, wpos].set(_kv_major(k_new), mode="drop")
-    v = cache["v"].at[:, b_idx, wpos].set(_kv_major(v_new), mode="drop")
-    K = k.shape[0]
-    G = cfg.num_heads // K
-    qg = q.reshape(B, C, K, G, cfg.head_dim).astype(jnp.float32)
-    scores = jnp.einsum("bqkgd,kbsd->bkgqs", qg, k.astype(jnp.float32))
-    scores = scores * (cfg.head_dim ** -0.5)
-    mask = jnp.arange(smax)[None, None, :] <= positions[:, :, None]
-    scores = jnp.where(mask[:, None, None, :, :], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgqs,kbsd->bqkgd", probs, v.astype(jnp.float32))
-    out = out.reshape(B, C, cfg.q_dim).astype(x.dtype)
-    return out @ p["wo"], {"k": k, "v": v}
+    new = {"k": _write_rows(cache["k"], _kv_major(k_new), layer, start,
+                            active),
+           "v": _write_rows(cache["v"], _kv_major(v_new), layer, start,
+                            active)}
+    k, v = new["k"], new["v"]
+    if layer is not None:
+        k, v = (jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
+                for c in (k, v))
+    out = _chunk_attention(cfg, q, k, v, positions)
+    return out.astype(x.dtype) @ p["wo"], new
 
 
-def apply_attention_decode(cfg, p, x, cache, pos, active=None):
-    """One-token decode. x: [B, 1, d]; cache: {k,v: [K, B, Smax, hd]};
-    pos: [B] int32 (index of the new token); active: optional [B] bool —
-    inactive slots leave the cache untouched (continuous batching).
-    Returns (out, new_cache)."""
+def apply_attention_decode(cfg, p, x, cache, pos, active=None, layer=None):
+    """One-token decode. x: [B, 1, d]; cache: {k,v: [K, B, Smax, hd]}, or
+    with ``layer`` (an int32 scalar) the whole stack {k,v: [L, K, B, Smax,
+    hd]}, of which layer ``layer`` is written in place and read by the
+    kernel through its index maps; pos: [B] int32 (index of the new
+    token); active: optional [B] bool — inactive slots leave the cache
+    untouched (continuous batching).  Returns (out, new_cache)."""
     B = x.shape[0]
     q, k_new, v_new = _qkv(cfg, p, x, pos[:, None])
-    b_idx = jnp.arange(B)
-    smax = cache["k"].shape[2]
-    wpos = pos if active is None else jnp.where(active, pos, smax)
-    k = cache["k"].at[:, b_idx, wpos].set(_kv_major(k_new[:, 0]),
-                                          mode="drop")
-    v = cache["v"].at[:, b_idx, wpos].set(_kv_major(v_new[:, 0]),
-                                          mode="drop")
+    k = _write_rows(cache["k"], _kv_major(k_new), layer, pos, active)
+    v = _write_rows(cache["v"], _kv_major(v_new), layer, pos, active)
     # position p attended iff p <= pos, i.e. p < pos + 1 == kv_len.  The
     # dispatcher's ref path is bit-identical to the previous inline einsum
     # formulation; on TPU / REPRO_PALLAS=interpret the Sq=1 Pallas decode
     # kernel skips the dead cache tail per slot.
     out = ops.decode_attention(q[:, 0], k, v, pos + 1,
+                               0 if layer is None else layer,
                                scale=cfg.head_dim ** -0.5)
     out = out.reshape(B, 1, cfg.q_dim)
     return out @ p["wo"], {"k": k, "v": v}

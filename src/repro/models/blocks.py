@@ -119,9 +119,11 @@ def make_block_cache_paged(cfg, mixer: str, batch: int, num_pages: int,
 
 
 def apply_block_decode(cfg, p, h, cache, pos, mixer: str, ffn: str,
-                       active=None, page_table=None):
+                       active=None, page_table=None, layer=None):
     """One-token decode. ``page_table`` not None selects the paged cache
     layout for attention/MLA mixers (mamba state is dense either way).
+    ``layer`` not None: ``cache`` is the whole layer stack of a dense
+    attention segment, written and read at that layer in place.
     Returns (h, new_cache)."""
     with jax.named_scope(MIXER_SCOPE[mixer]):
         x = rmsnorm(h, p["ln1"], cfg.norm_eps)
@@ -131,7 +133,8 @@ def apply_block_decode(cfg, p, h, cache, pos, mixer: str, ffn: str,
                                 active)
                             if page_table is not None
                             else attn_mod.apply_attention_decode(
-                                cfg, p["mixer"], x, cache, pos, active))
+                                cfg, p["mixer"], x, cache, pos, active,
+                                layer))
         elif mixer == "mla":
             r, new_cache = (mla_mod.apply_mla_decode_paged(
                                 cfg, p["mixer"], x, cache, pos, page_table,
@@ -147,10 +150,11 @@ def apply_block_decode(cfg, p, h, cache, pos, mixer: str, ffn: str,
 
 
 def apply_block_prefill_chunk(cfg, p, h, cache, start, mixer: str, ffn: str,
-                              active=None, page_table=None):
+                              active=None, page_table=None, layer=None):
     """Chunked prefill through one block. h: [B, C, d]; start: [B] int32
     per-slot cache offset of the chunk; ``page_table`` not None selects
-    the paged layout for attention/MLA. Returns (h, new_cache)."""
+    the paged layout for attention/MLA; ``layer`` as in
+    ``apply_block_decode``. Returns (h, new_cache)."""
     with jax.named_scope(MIXER_SCOPE[mixer]):
         x = rmsnorm(h, p["ln1"], cfg.norm_eps)
         if mixer == "attn":
@@ -159,7 +163,8 @@ def apply_block_prefill_chunk(cfg, p, h, cache, start, mixer: str, ffn: str,
                                 active)
                             if page_table is not None
                             else attn_mod.apply_attention_prefill_chunk(
-                                cfg, p["mixer"], x, cache, start, active))
+                                cfg, p["mixer"], x, cache, start, active,
+                                layer))
         elif mixer == "mla":
             r, new_cache = (mla_mod.apply_mla_prefill_chunk_paged(
                                 cfg, p["mixer"], x, cache, start, page_table,

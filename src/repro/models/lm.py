@@ -310,6 +310,44 @@ def make_cache(cfg, batch_size: int, max_seq: int,
     return out
 
 
+def _cache_scan(cfg, params, h, cache, page_table, apply, unroll):
+    """Run ``h`` through every layer, each segment's stacked cache riding
+    the layer scan's carry, so XLA updates it in place at every loop level
+    (the step programs donate it).
+
+    ``apply(seg, layer_p, h, c, layer)`` runs one layer and returns
+    ``(h, c)``.  A dense attention segment (no page table) hands it the
+    whole stack and the layer index: attention writes its new rows and
+    reads the layer in place.  Other segments (MLA, mamba, the hybrid
+    super-block, a paged pool) get ``layer`` None and the layer's own
+    leaves, read out of the carry and written back after.
+    Returns (h, new_cache)."""
+    new_caches = []
+    for seg, seg_params, seg_cache in zip(segments(cfg), params["segments"],
+                                          cache, strict=False):
+        whole = (seg.kind == "blocks" and seg.mixer == "attn"
+                 and page_table is None)
+
+        def body(carry, xs, seg=seg, whole=whole):
+            hh, c = carry
+            layer_p, layer = xs
+            if whole:
+                return apply(seg, layer_p, hh, c, layer), None
+            at = jax.tree_util.tree_map(
+                lambda x: jax.lax.dynamic_index_in_dim(x, layer, 0, False), c)
+            hh, at = apply(seg, layer_p, hh, at, None)
+            c = jax.tree_util.tree_map(
+                lambda x, y: jax.lax.dynamic_update_index_in_dim(
+                    x, y, layer, 0), c, at)
+            return (hh, c), None
+
+        (h, new_c), _ = jax.lax.scan(
+            body, (h, seg_cache), (seg_params, jnp.arange(seg.count)),
+            unroll=seg.count if unroll else 1)
+        new_caches.append(new_c)
+    return h, new_caches
+
+
 def prefill_chunk(cfg, params, batch, cache, *, unroll: bool = False):
     """Prefill a C-token chunk into slot caches (continuous batching).
 
@@ -322,25 +360,18 @@ def prefill_chunk(cfg, params, batch, cache, *, unroll: bool = False):
     tokens, start = batch["tokens"], batch["start"]
     active = batch.get("active")
     page_table = batch.get("page_table")
-    h = embed_tokens(cfg, params, tokens, batch)
-    new_caches = []
-    for seg, seg_params, seg_cache in zip(segments(cfg), params["segments"],
-                                          cache, strict=False):
-        def body(carry, xs, seg=seg):
-            hh = carry
-            layer_p, layer_c = xs
-            hh, nc = (B.apply_super_block_prefill_chunk(
-                          cfg, layer_p, hh, layer_c, start, seg.plan, active,
-                          page_table)
-                      if seg.kind == "hybrid"
-                      else B.apply_block_prefill_chunk(
-                          cfg, layer_p, hh, layer_c, start, seg.mixer,
-                          seg.ffn, active, page_table))
-            return hh, nc
 
-        h, new_c = jax.lax.scan(body, h, (seg_params, seg_cache),
-                                unroll=seg.count if unroll else 1)
-        new_caches.append(new_c)
+    def apply(seg, p, hh, c, layer):
+        if seg.kind == "hybrid":
+            return B.apply_super_block_prefill_chunk(
+                cfg, p, hh, c, start, seg.plan, active, page_table)
+        return B.apply_block_prefill_chunk(cfg, p, hh, c, start, seg.mixer,
+                                           seg.ffn, active, page_table,
+                                           layer)
+
+    h = embed_tokens(cfg, params, tokens, batch)
+    _, new_caches = _cache_scan(cfg, params, h, cache, page_table, apply,
+                                unroll)
     return new_caches
 
 
@@ -351,25 +382,17 @@ def decode_step(cfg, params, batch, cache, *, unroll: bool = False):
     tokens, pos = batch["tokens"], batch["pos"]
     active = batch.get("active")
     page_table = batch.get("page_table")
-    h = embed_tokens(cfg, params, tokens, batch)
-    new_caches = []
-    for seg, seg_params, seg_cache in zip(segments(cfg), params["segments"],
-                                          cache, strict=False):
-        def body(carry, xs, seg=seg):
-            hh = carry
-            layer_p, layer_c = xs
-            hh, nc = (B.apply_super_block_decode(cfg, layer_p, hh, layer_c,
-                                                 pos, seg.plan, active,
-                                                 page_table)
-                      if seg.kind == "hybrid"
-                      else B.apply_block_decode(cfg, layer_p, hh, layer_c,
-                                                pos, seg.mixer, seg.ffn,
-                                                active, page_table))
-            return hh, nc
 
-        h, new_c = jax.lax.scan(body, h, (seg_params, seg_cache),
-                                unroll=seg.count if unroll else 1)
-        new_caches.append(new_c)
+    def apply(seg, p, hh, c, layer):
+        if seg.kind == "hybrid":
+            return B.apply_super_block_decode(cfg, p, hh, c, pos, seg.plan,
+                                              active, page_table)
+        return B.apply_block_decode(cfg, p, hh, c, pos, seg.mixer, seg.ffn,
+                                    active, page_table, layer)
+
+    h = embed_tokens(cfg, params, tokens, batch)
+    h, new_caches = _cache_scan(cfg, params, h, cache, page_table, apply,
+                                unroll)
     h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
     logits = apply_head(cfg, params, h[:, -1])
     return logits, new_caches

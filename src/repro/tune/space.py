@@ -90,7 +90,7 @@ SPECS: dict[str, KernelSpec] = {
         shape_axes=("b", "sk", "h", "kvh", "d"),
         smoke_shape={"b": 4, "sk": 512, "h": 4, "kvh": 2, "d": 64},
         full_shape={"b": 16, "sk": 2048, "h": 8, "kvh": 2, "d": 64},
-        defaults={"block_k": 128},
+        defaults={"block_k": 512},
         tunables={"block_k": (64, 128, 256, 512)},
         pathological={"block_k": (8, 16)},
     ),

@@ -72,15 +72,50 @@ def test_scale_override_and_vdim():
                                atol=2e-5, rtol=2e-5)
 
 
-def test_ops_dispatch_ref_matches_kernel(monkeypatch):
+@pytest.mark.parametrize("H,K,Sk,block_k,lens", [
+    (8, 2, 64, 32, (1, 64, 30)),      # GQA groups of 4, ragged to 1 and Sk
+    (4, 4, 40, 16, (40, 1, 17)),      # no groups; Sk a block multiple + 8
+    (8, 1, 100, 32, (100, 3, 64)),    # one KV head; non-dividing Sk
+])
+def test_stacked_layer_matches_its_slice(H, K, Sk, block_k, lens):
+    """A stacked [L, K, B, Sk, D] cache read at layer l, through the
+    kernel's index maps, gives what the layer's own slice gives, bit for
+    bit, in the kernel and in the reference."""
+    L, B, D = 3, len(lens), 16
+    q, k, v = _inputs(B, L * Sk, H, K, D, seed=5)
+    k = k.reshape(K, B, L, Sk, D).transpose(2, 0, 1, 3, 4)
+    v = v.reshape(K, B, L, Sk, D).transpose(2, 0, 1, 3, 4)
+    kv_len = jnp.array(lens, jnp.int32)
+    kern = jax.jit(lambda *a: decode_attention(*a, block_k=block_k,
+                                               interpret=True))
+    for layer in range(L):
+        l = jnp.int32(layer)
+        got = kern(q, k, v, kv_len, l)
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(kern(q, k[layer], v[layer], kv_len)))
+        ref = decode_attention_ref(q, k, v, kv_len, l)
+        np.testing.assert_array_equal(
+            np.asarray(ref),
+            np.asarray(decode_attention_ref(q, k[layer], v[layer], kv_len)))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("stacked", [False, True])
+def test_ops_dispatch_ref_matches_kernel(stacked, monkeypatch):
     from repro.kernels import ops
     B, Sk, H, K, D = 2, 48, 4, 2, 16
     q, k, v = _inputs(B, Sk, H, K, D, seed=4)
     kv_len = jnp.array([9, 48], jnp.int32)
+    layer = ()
+    if stacked:     # the layer of interest between two others
+        k = jnp.stack([k[::-1], k, -k])
+        v = jnp.stack([-v, v, v[::-1]])
+        layer = (jnp.int32(1),)
     monkeypatch.setenv("REPRO_PALLAS", "ref")
-    via_ref = ops.decode_attention(q, k, v, kv_len)
+    via_ref = ops.decode_attention(q, k, v, kv_len, *layer)
     monkeypatch.setenv("REPRO_PALLAS", "interpret")
-    via_kernel = ops.decode_attention(q, k, v, kv_len)
+    via_kernel = ops.decode_attention(q, k, v, kv_len, *layer)
     np.testing.assert_allclose(np.asarray(via_kernel), np.asarray(via_ref),
                                atol=2e-5, rtol=2e-5)
 

@@ -11,6 +11,7 @@ The topology is described inside a module-scoped fixture, never at import
 time: only one process at a time may load the TPU library, and every
 pytest-xdist worker imports this file.
 """
+import math
 import re
 
 import jax
@@ -168,7 +169,7 @@ def test_sharded_train_step_compiles_smollm_360m(mesh_2x2, kernel_dispatch,
     assert "tpu_custom_call" in jax.jit(step).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("layout", ["dense", "paged"])
+@pytest.mark.parametrize("layout", ["dense", "stacked", "paged"])
 def test_sharded_decode_keeps_the_cache_local(layout, mesh_2x2,
                                               kernel_dispatch,
                                               no_compile_cache):
@@ -190,6 +191,11 @@ def test_sharded_decode_keeps_the_cache_local(layout, mesh_2x2,
         cache = sds((K, SLOTS, MAX_SEQ, D), jnp.bfloat16,
                     ("kv_heads", "batch", "seq_kv", None))
         fn, args = ops.decode_attention, (q, cache, cache, kv_len)
+    elif layout == "stacked":   # every layer's cache, read at one layer
+        cache = sds((4, K, SLOTS, MAX_SEQ, D), jnp.bfloat16,
+                    (None, "kv_heads", "batch", "seq_kv", None))
+        layer = sds((), jnp.int32, ())
+        fn, args = ops.decode_attention, (q, cache, cache, kv_len, layer)
     else:
         W = MAX_SEQ // PAGE
         pool = sds((K, SLOTS * W, PAGE, D), jnp.bfloat16,
@@ -204,6 +210,71 @@ def test_sharded_decode_keeps_the_cache_local(layout, mesh_2x2,
     hlo = jax.jit(decode).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
     assert "all-gather" not in hlo
+
+
+def _cache_sized_copies(hlo: str, shapes) -> list[str]:
+    """Copies and dynamic-update-slice fusions whose result has one of
+    ``shapes``, unit dims aside.  An unfused dynamic-update-slice updates
+    its operand in place and is not counted."""
+    out = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]",
+                         hlo, re.M):
+        name = m.group(1)
+        dims = tuple(int(d) for d in m.group(2).split(",") if d not in
+                     ("", "1"))
+        if dims in shapes and (name.startswith("copy") or (
+                "dynamic-update-slice" in name and "fusion" in name)):
+            out.append(name)
+    return out
+
+
+def test_step_programs_write_the_kv_cache_in_place(one_chip,
+                                                   kernel_dispatch,
+                                                   no_compile_cache):
+    """qwen3-4b's fused decode and chunked prefill programs, 4 layers
+    deep with 8 slots x 2048, carry the stacked KV cache through their
+    layer loops: no copy of the whole cache or of one layer's [K, B, S,
+    hd] slice, in or around the loops.  The decode kernel reads the
+    stacked cache and keeps the signature that
+    ``chipbench/metrics/decode_attention_roofline.py`` finds it by: four
+    operands and one [heads, head_dim] row per slot."""
+    from repro.configs import get_config
+    from repro.models.params import abstract_params
+    from repro.serve.engine import _fused_steps, _prefill_chunk
+
+    H, K, D = ATTN["qwen3-4b"]
+    L, C = 4, 128
+    cfg = get_config("qwen3-4b").replace(num_layers=L)
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+    arg = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    params = on_chip(abstract_params(lm.make_lm(cfg)))
+    cache = on_chip(abstract_params(lm.make_cache(cfg, SLOTS, MAX_SEQ)))
+    shapes = {(L, K, SLOTS, MAX_SEQ, D), (K, SLOTS, MAX_SEQ, D)}
+    state = {"tokens": arg((SLOTS, 1)), "pos": arg((SLOTS,)),
+             "cursor": arg((SLOTS,)), "plen": arg((SLOTS,)),
+             "remaining": arg((SLOTS,)), "live": arg((SLOTS,), bool),
+             "keys": arg((SLOTS, 2), jnp.uint32)}
+    fused = _fused_steps.lower(
+        cfg, 8, params, cache, state, arg((SLOTS, MAX_SEQ)),
+        arg((SLOTS,), jnp.float32), arg((SLOTS,)), None, None
+    ).compile().as_text()
+    assert _cache_sized_copies(fused, shapes) == []
+    calls = [ln for ln in fused.splitlines()
+             if "tpu_custom_call" in ln and "custom-call(" in ln]
+    assert calls
+    for ln in calls:
+        result = re.match(r"\s*%?[\w.\-]+ = \w+\[([\d,]*)\]", ln).group(1)
+        operands = ln.partition("custom-call(")[2].partition(
+            "), custom_call_target")[0]
+        assert math.prod(int(d) for d in result.split(",")) == SLOTS * H * D
+        assert operands.count("%") == 4, ln
+    chunk = _prefill_chunk.lower(
+        cfg, params, cache, arg((SLOTS, C)), arg((SLOTS,)),
+        arg((SLOTS,), bool), None).compile().as_text()
+    assert _cache_sized_copies(chunk, shapes) == []
 
 
 def test_train_step_names_the_attention_backward(one_chip, kernel_dispatch,
