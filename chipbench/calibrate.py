@@ -9,9 +9,11 @@ own runs never run this.
 ``readings`` runs the cell once per seed in one process, at the cell's own
 load and sizes, and prints each number the comparison reads; on the
 control seeds it also reads the control (the reference in float8) and, for
-a training cell, the planted half-batch fault.  ``sweep`` offers an open
-loop at each rate in turn to one engine and prints how the backlog and the
-time to first token grow, to find the highest rate the engine sustains.
+a training cell, the planted half-batch fault and, on a ZeRO-1 mesh, the
+planted fault that drops half of each tensor's update.  ``sweep`` offers
+an open loop at each rate in turn to one engine and prints how the backlog
+and the time to first token grow, to find the highest rate the engine
+sustains.
 Each line of standard output is one JSON object.
 """
 from __future__ import annotations
@@ -48,17 +50,17 @@ def sweep(args, cell, device) -> None:
     from repro.serve.engine import DecodeEngine
 
     mix, e = cell.traffic, cell.traffic["engine"]
-    arch, pcfg, _, gen = run._model(cell)
-    params = run._program_params(gen, pcfg, 0)
+    model, pcfg, gen = run._model(cell)
+    params = run._program_params(model, gen, pcfg, 0)
     eng = DecodeEngine(pcfg, params, batch_slots=e["slots"],
                        max_seq=e["max_seq"], rng_seed=0, mode=e["mode"],
                        steps_per_sync=e["steps_per_sync"],
                        prefill_chunk=e["prefill_chunk"],
                        kv_layout=e["kv_layout"])
-    serve.warm_up(eng, mix, arch.vocab, 0)
+    serve.warm_up(eng, mix, model.vocab, 0)
     for rate in (float(r) for r in args.rates.split(",")):
         m = dict(mix, rate_per_s=rate)
-        items = traffic.open_loop(m, 1, args.seconds, arch.vocab)
+        items = traffic.open_loop(m, 1, args.seconds, model.vocab)
         loop = serve.Loop(eng, m, items, args.seconds, time.perf_counter)
         queue = []
         step = loop._step

@@ -29,7 +29,7 @@ from dataclasses import dataclass, field
 import jax
 import numpy as np
 
-from harness import traffic
+from harness import quiet, traffic
 from harness.stats import percentile
 
 SPAN = "chipbench.engine_step"
@@ -57,7 +57,6 @@ class StepRecord:
     t0: float
     t1: float
     decode: list = field(default_factory=list)   # [(start_pos, n_steps)]
-    prefill_tokens: int = 0
 
 
 def _prompt_done(eng, slot: int) -> int:
@@ -111,7 +110,6 @@ class Loop:
     def _step(self) -> StepRecord:
         eng = self.eng
         pos0, live0 = eng.pos.copy(), eng.live.copy()
-        pf0 = eng.pf_done.copy()
         before = {id(r): s for s, r in enumerate(eng.slot_req) if r is not None}
         t0 = self.clock()
         with (jax.profiler.TraceAnnotation(SPAN) if self.tracing
@@ -128,9 +126,6 @@ class Loop:
                 decoding = started or eng.live[slot] or tr.req.done
                 if decoding and eng.pos[slot] > begin:
                     rec.decode.append((begin, int(eng.pos[slot]) - begin))
-                if not started:
-                    prev = int(pf0[slot]) if rid in before else 0
-                    rec.prefill_tokens += int(eng.pf_done[slot]) - prev
             self._observe(tr, slot, rec)
         return rec
 
@@ -172,6 +167,10 @@ class Loop:
 
     # -- the run -----------------------------------------------------------
     def run(self, preroll: float) -> None:
+        with quiet.no_collection():
+            self._run(preroll)
+
+    def _run(self, preroll: float) -> None:
         self.tracing = False
         self.t_sched = self.clock()
         self.w0 = self.t_sched + preroll

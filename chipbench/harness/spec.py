@@ -1,13 +1,16 @@
 """What a cell is: its entry in ``BENCHMARK.json`` and the data files that
 entry names.  Files are found by name: ``configs`` entries give their file,
-a traffic mix is ``traffic/<name>.json`` and a per-layer metric's reader is
+a configuration's plain reference is ``reference/<its "reference">.py``, a
+traffic mix is ``traffic/<name>.json`` and a per-layer metric's reader is
 ``metrics/<name>.py``.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
+import sys
 from dataclasses import dataclass
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -52,36 +55,73 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
 
 def metric_reader(name: str):
     """The ``read`` function of ``metrics/<name>.py``."""
-    path = os.path.join(BENCH, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(os.path.join(BENCH, "metrics", f"{name}.py"),
+                 f"chipbench_metric_{name.replace('.', '_')}").read
+
+
+def _known_families() -> set:
+    """The families of the registry's models (``configs/registry.py``)."""
+    from repro.configs.registry import ARCH_IDS, get_config
+
+    return {get_config(a).family for a in ARCH_IDS}
+
+
+def _build(cls, fields: dict, where: str):
+    """``cls(**fields)`` for a dataclass of the registry, with each nested
+    dataclass built from its dict; a field ``cls`` lacks stops the run."""
+    import dataclasses
+    import typing
+
+    hints = typing.get_type_hints(cls)
+    known = {f.name for f in dataclasses.fields(cls)}
+    unknown = sorted(set(fields) - known)
+    if unknown:
+        raise SystemExit(f"{where}: {cls.__name__} has no field "
+                         f"{', '.join(map(repr, unknown))}")
+    kw = {}
+    for k, v in fields.items():
+        sub = [t for t in typing.get_args(hints[k]) or (hints[k],)
+               if dataclasses.is_dataclass(t)]
+        kw[k] = _build(sub[0], v, f"{where}.{k}") \
+            if sub and isinstance(v, dict) else v
+    return cls(**kw)
 
 
 def program_config(c: dict):
-    """The program's ``ModelConfig`` for configuration file ``c``."""
+    """The program's ``ModelConfig`` for configuration file ``c``: its
+    ``name``, its ``family`` and the registry's field values under
+    ``model_config``.  A family the registry does not have, or a field
+    ``ModelConfig`` does not have, stops the run with its name."""
     from repro.configs.base import ModelConfig
 
-    qk_norm = {"qwen3": True, "llama": False}[c["model_type"]]
-    return ModelConfig(
-        name=c["name"], family="dense",
-        num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        qk_norm=qk_norm, rope_theta=float(c["rope_theta"]),
-        act=c["hidden_act"], norm_eps=c["rms_norm_eps"],
-        tie_embeddings=c["tie_word_embeddings"], dtype=c["dtype"])
+    families = _known_families()
+    if c.get("family") not in families:
+        raise SystemExit(f"configuration {c.get('name')!r}: unknown family "
+                         f"{c.get('family')!r}; the registry has "
+                         f"{sorted(families)}")
+    return _build(ModelConfig, {"name": c["name"], "family": c["family"],
+                                **c["model_config"]},
+                  f"configuration {c['name']!r}")
 
 
-def reference_spec(c: dict):
-    from reference.dense_gqa import Spec
+@functools.cache
+def _load(path: str, module: str):
+    spec = importlib.util.spec_from_file_location(module, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[module] = mod       # dataclasses look their module up here
+    spec.loader.exec_module(mod)
+    return mod
 
-    return Spec(layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-                heads=c["num_attention_heads"],
-                kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
-                d_ff=c["intermediate_size"], vocab=c["vocab_size"],
-                rope_theta=float(c["rope_theta"]), eps=c["rms_norm_eps"],
-                qk_norm=c["model_type"] == "qwen3")
+
+# where a configuration's ``"reference"`` is looked up
+REFERENCES = os.path.join(BENCH, "reference")
+
+
+def reference_model(c: dict):
+    """The plain reference of configuration file ``c``: ``build(c)`` of
+    ``reference/<c["reference"]>.py``."""
+    path = os.path.join(REFERENCES, f"{c['reference']}.py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"configuration {c['name']!r}: no reference "
+                         f"module {path}")
+    return _load(path, f"chipbench_reference_{c['reference']}").build(c)

@@ -1,5 +1,6 @@
 """The training driver: the program's jitted ``make_train_step`` (AdamW,
-remat as shipped, bfloat16) in a closed loop over seeded batches.
+remat as shipped, bfloat16) in a closed loop over seeded batches, on one
+chip or over a mesh of chips.
 
 Set-up builds one object, the compiled step with its state, and drives it
 through its first ``check_steps`` steps on the window's own call and feed;
@@ -16,7 +17,7 @@ import sys
 import jax
 import jax.numpy as jnp
 
-from harness import check
+from harness import check, quiet
 
 SPAN = "chipbench.train_step"
 
@@ -26,20 +27,60 @@ def log(msg: str) -> None:
 
 
 class Trainer:
-    def __init__(self, pcfg, spec, mix: dict, params, batches):
+    def __init__(self, pcfg, model, mix: dict, params, batches):
+        """``mix`` may lay the step over a mesh (``"mesh"``: {axis: size},
+        ``"zero1"``): parameters and optimizer state are then placed by
+        the program's sharding rules, ZeRO-1 spreading the optimizer state
+        over the data axis, and each batch is split over the data axis."""
         from repro.train.optimizer import AdamW
         from repro.train.schedule import constant
         from repro.train.train_step import make_train_step
 
-        self.mix, self.spec = mix, spec
+        self.mix, self.model = mix, model
+        self.per_layer = check.per_layer_names(model)
         self.opt = AdamW()
         step = make_train_step(pcfg, self.opt, constant(mix["lr"]),
                                clip_norm=mix["clip_norm"], remat=mix["remat"])
-        self.jstep = jax.jit(step, donate_argnums=(0, 1))
+        rows = [batches[i] for i in range(batches.shape[0])]
+        if "mesh" in mix:
+            step, placed, params, rows = self._on_mesh(pcfg, step, params,
+                                                       rows)
+        else:
+            placed = {}
+            self.opt_state = jax.jit(self.opt.init)(params)
+        self.jstep = jax.jit(step, donate_argnums=(0, 1), **placed)
         self.params = params
-        self.opt_state = jax.jit(self.opt.init)(params)
-        self.batches = [batches[i] for i in range(batches.shape[0])]
+        self.batches = rows
         self.i = 0
+
+    def _on_mesh(self, pcfg, step, params, rows):
+        """The step under the mesh's rules, its shardings, and the state and
+        batches placed by them."""
+        from repro.launch.mesh import make_mesh
+        from repro.models import lm
+        from repro.models.params import param_shardings
+        from repro.sharding.rules import make_rules, use_rules
+        from repro.sharding.zero import opt_state_shardings
+
+        axes = tuple(self.mix["mesh"])
+        rules = make_rules(make_mesh(tuple(self.mix["mesh"][a] for a in axes),
+                                     axes))
+        descr = lm.make_lm(pcfg)
+        p_sh = param_shardings(descr, rules)
+        o_sh = opt_state_shardings("adamw", descr, rules,
+                                   zero1=self.mix["zero1"])
+        b_sh = rules.sharding(("batch", "seq"), rows[0].shape)
+        params = jax.device_put(params, p_sh)
+        self.opt_state = jax.jit(self.opt.init, out_shardings=o_sh)(params)
+        rows = [jax.device_put(r, b_sh) for r in rows]
+
+        def train_step(*a):         # the module name the readers look for
+            with use_rules(rules):
+                return step(*a)
+
+        placed = {"in_shardings": (p_sh, o_sh, {"tokens": b_sh}, None),
+                  "out_shardings": (p_sh, o_sh, None)}
+        return train_step, placed, params, rows
 
     def step(self):
         b = self.batches[self.i % len(self.batches)]
@@ -59,15 +100,17 @@ class Trainer:
             if not bool(jnp.isfinite(loss)):    # the window's own check
                 raise RuntimeError(f"step {self.i} lost its loss: {loss}")
             losses.append(float(loss))
-            if grad is None:
-                g = jax.tree_util.tree_map(lambda m: m / (1 - self.opt.b1),
-                                           self.opt_state["m"])
-                grad = check.slice_norms(to_ref(g), self.spec.layers)
+            if grad is None:            # the moment's copy dies here
+                grad = check.slice_norms(to_ref(jax.tree_util.tree_map(
+                    lambda m: m / (1 - self.opt.b1), self.opt_state["m"])),
+                    self.per_layer)
         w0 = w0_fn()
         master = to_ref(self.opt_state["master"])
-        change = check.slice_norms(
-            {k: master[k] - w0[k].astype(jnp.float32) for k in master},
-            self.spec.layers)
+        change = {}
+        for k in master:                # one tensor's change live at a time
+            change |= check.slice_norms(
+                {k: master[k] - jax.device_put(w0[k], master[k].sharding)
+                 .astype(jnp.float32)}, self.per_layer)
         del w0
         return {"losses": losses, "grad": grad, "change": change}
 
@@ -75,6 +118,10 @@ class Trainer:
                trace_seconds: float = 0.0) -> dict:
         """Steps for ``seconds``; one step stays queued behind the one the
         host waits on."""
+        with quiet.no_collection():
+            return self._window(seconds, clock, trace_dir, trace_seconds)
+
+    def _window(self, seconds, clock, trace_dir, trace_seconds) -> dict:
         tokens = self.mix["batch"] * self.mix["seq_len"]
         t0 = clock()
         done, bad, prev = 0, 0, None

@@ -1,5 +1,6 @@
 """Share (%) of the traced training window in which no operation ran on
-the device: 1 - the union of busy intervals over the window."""
+the device: 1 - the union of busy intervals over the window, averaged over
+the chips."""
 from harness.trace import busy_ns
 
 

@@ -10,6 +10,15 @@ nothing of the program under test.
 narrower type (one float32 scale per output channel) before use: the
 control that a comparison must fail.
 
+``build(config)`` gives the model the harness reads (the interface is in
+``common.py``): the layout, the map to the program's parameter tree, and
+the operations and bytes the work needs, from the published sizes and the
+real lengths of the work (adapted from the repository's
+``configs/analysis.model_flops``, 6·N·T plus attention).  The arithmetic
+counts what the algorithm needs, never what one implementation happens to
+touch, so a roofline share reads the same work whatever implements the
+kernel; a multiply-add is two operations.
+
 Weights are a dict of named tensors (``embed``, ``final_norm``, optional
 ``head`` ``[d, V]``, and the layer tensors ``ln1 wq wk wv wo q_norm k_norm
 ln2 up gate down``, each stacked ``[layers, ...]``).  Layers are applied one
@@ -24,7 +33,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-HIGHEST = jax.lax.Precision.HIGHEST
+from reference.common import HIGHEST, Tensor, fake_quant
+
+BF16 = 2
 LAYER_NAMES = ("ln1", "wq", "wk", "wv", "wo", "q_norm", "k_norm", "ln2",
                "up", "gate", "down")
 
@@ -41,6 +52,7 @@ class Spec:
     rope_theta: float
     eps: float
     qk_norm: bool
+    tied: bool = True
 
 
 NORMS = ("ln1", "ln2", "q_norm", "k_norm", "final_norm")
@@ -48,29 +60,6 @@ NORMS = ("ln1", "ln2", "q_norm", "k_norm", "final_norm")
 
 def _mm(a, b):
     return jnp.matmul(a, b, precision=HIGHEST)
-
-
-def round_to(x, dtype):
-    """``x`` rounded to the nearest value of the narrow float ``dtype``
-    (saturating at its largest finite value), by arithmetic alone, so that
-    no compiler can treat the round trip through ``dtype`` as a no-op."""
-    fi = jnp.finfo(dtype)
-    top = float(fi.max)
-    a = jnp.minimum(jnp.abs(x), top)
-    _, e = jnp.frexp(a)                                  # a = m * 2^e, m in [0.5, 1)
-    e = jnp.maximum(e - 1, int(fi.minexp))               # subnormals share minexp
-    step = jnp.ldexp(jnp.ones_like(a), e - int(fi.nmant))
-    return jnp.sign(x) * jnp.minimum(jnp.round(a / step) * step, top)
-
-
-def fake_quant(x, axis: int, dtype):
-    """``x`` rounded to ``dtype`` with one scale per slice along ``axis``
-    (the reduced axis), returned in float32."""
-    x = x.astype(jnp.float32)
-    top = float(jnp.finfo(dtype).max)
-    scale = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / top
-    scale = jnp.where(scale > 0, scale, 1.0)
-    return round_to(x / scale, dtype) * scale
 
 
 def _as_computed(name, x, weight_dtype):
@@ -183,28 +172,151 @@ def loss(spec: Spec, w, tokens, weight_dtype=None):
     return jnp.mean(nll)
 
 
-@dataclass(frozen=True)
-class AdamW:
-    """AdamW with bias correction and decoupled weight decay, after global
-    gradient-norm clipping."""
-    lr: float
-    b1: float = 0.9
-    b2: float = 0.95
-    eps: float = 1e-8
-    weight_decay: float = 0.1
-    clip_norm: float = 1.0
+# ---------------------------------------------------------------------------
+# the model the harness reads
+# ---------------------------------------------------------------------------
+# reference name -> path in the program's parameter tree; layer tensors are
+# stacked on a leading layer axis under segment 0
+PROGRAM_PATHS = {
+    "ln1": ("ln1",),
+    "wq": ("mixer", "wq"), "wk": ("mixer", "wk"), "wv": ("mixer", "wv"),
+    "wo": ("mixer", "wo"),
+    "q_norm": ("mixer", "q_norm"), "k_norm": ("mixer", "k_norm"),
+    "ln2": ("ln2",),
+    "up": ("ffn", "wi"), "gate": ("ffn", "wg"), "down": ("ffn", "wo"),
+}
 
-    def clip(self, g):
-        norm = jnp.sqrt(sum(jnp.sum(x * x) for x in jax.tree_util.tree_leaves(g)))
-        scale = jnp.minimum(1.0, self.clip_norm / (norm + 1e-9))
-        return jax.tree_util.tree_map(lambda x: x * scale, g)
 
-    def step(self, w, m, v, g, t):
-        """One update at step ``t`` (1-based) with clipped gradients g."""
-        m = jax.tree_util.tree_map(lambda a, b: self.b1 * a + (1 - self.b1) * b, m, g)
-        v = jax.tree_util.tree_map(lambda a, b: self.b2 * a + (1 - self.b2) * b * b, v, g)
-        c1, c2 = 1 - self.b1 ** t, 1 - self.b2 ** t
-        w = jax.tree_util.tree_map(
-            lambda p, a, b: p - self.lr * ((a / c1) / (jnp.sqrt(b / c2) + self.eps)
-                                           + self.weight_decay * p), w, m, v)
-        return w, m, v
+class Model:
+    """A dense GQA decoder of sizes ``spec``: the reference's side of the
+    harness's interface (``common.py``)."""
+
+    def __init__(self, spec: Spec):
+        self.spec = spec
+        self.layers, self.vocab = spec.layers, spec.vocab
+        self.heads, self.kv_heads = spec.heads, spec.kv_heads
+        self.head_dim, self.d_model, self.d_ff = (spec.head_dim,
+                                                  spec.d_model, spec.d_ff)
+
+    # -- weights -------------------------------------------------------------
+    def layout(self) -> dict:
+        d, hd, s = self.d_model, self.head_dim, self.spec
+        out = {"embed": Tensor((self.vocab, d), False, "embed", 0),
+               "final_norm": Tensor((d,), False, "norm")}
+        if not s.tied:
+            out["head"] = Tensor((d, self.vocab), False, "fan_in", 1)
+        layer = {"ln1": ((d,), None), "wq": ((d, self.heads * hd), 1),
+                 "wk": ((d, self.kv_heads * hd), 1),
+                 "wv": ((d, self.kv_heads * hd), 1),
+                 "wo": ((self.heads * hd, d), 0), "ln2": ((d,), None),
+                 "up": ((d, self.d_ff), 1), "gate": ((d, self.d_ff), 1),
+                 "down": ((self.d_ff, d), 0)}
+        if s.qk_norm:
+            layer.update(q_norm=((hd,), None), k_norm=((hd,), None))
+        for name, (shape, split) in layer.items():
+            out[name] = Tensor(shape, True, "norm" if name in NORMS
+                               else "fan_in", split)
+        return out
+
+    def to_program(self, w: dict) -> dict:
+        """The program's parameter tree over the same arrays (no copies)."""
+        seg: dict = {}
+        for name, path in PROGRAM_PATHS.items():
+            if name in w:
+                node = seg
+                for p in path[:-1]:
+                    node = node.setdefault(p, {})
+                node[path[-1]] = w[name]
+        tree = {"embed": w["embed"], "segments": [seg],
+                "final_norm": w["final_norm"]}
+        if "head" in w:
+            tree["lm_head"] = w["head"]
+        return tree
+
+    def from_program(self, tree) -> dict:
+        """{reference name: array} of a tree laid out as the program's
+        parameters (the inverse of ``to_program``)."""
+        out = {"embed": tree["embed"], "final_norm": tree["final_norm"]}
+        if "lm_head" in tree:
+            out["head"] = tree["lm_head"]
+        seg = tree["segments"][0]
+        for name, path in PROGRAM_PATHS.items():
+            node = seg
+            for p in path:
+                node = node.get(p) if isinstance(node, dict) else None
+                if node is None:
+                    break
+            if node is not None:
+                out[name] = node
+        return out
+
+    # -- the forward pass and the loss ---------------------------------------
+    def final_hidden(self, w, tokens, weight_dtype=None):
+        return final_hidden(self.spec, w, tokens, weight_dtype)
+
+    def head_matrix(self, w, weight_dtype=None):
+        return head_matrix(w, weight_dtype)
+
+    def loss(self, w, tokens, weight_dtype=None):
+        return loss(self.spec, w, tokens, weight_dtype)
+
+    # -- arithmetic ----------------------------------------------------------
+    @property
+    def layer_matmul_params(self) -> int:
+        """Weights one token multiplies through in one block: q, k, v and
+        o projections and the three SwiGLU matrices."""
+        d, hd = self.d_model, self.head_dim
+        attn = d * self.heads * hd * 2 + d * self.kv_heads * hd * 2
+        return attn + 3 * d * self.d_ff
+
+    @property
+    def head_params(self) -> int:
+        return self.d_model * self.vocab
+
+    def attention_flops(self, context: int) -> int:
+        """Scores and weighted values of one query token over ``context``
+        keys, all layers."""
+        return 4 * self.layers * self.heads * self.head_dim * context
+
+    def decode_token_flops(self, kv_len: int) -> int:
+        """Forward of one decoded token whose cache holds ``kv_len`` rows
+        (itself included), head included."""
+        return (2 * (self.layers * self.layer_matmul_params + self.head_params)
+                + self.attention_flops(kv_len))
+
+    def train_step_flops(self, batch: int, seq: int) -> int:
+        """Forward and backward (3x the forward) of one causal-LM step on
+        ``batch`` rows of ``seq`` tokens; the head scores ``seq - 1``
+        positions.  Recomputation is not counted."""
+        body = seq * 2 * self.layers * self.layer_matmul_params
+        head = (seq - 1) * 2 * self.head_params
+        attn = 4 * self.layers * self.heads * self.head_dim * seq * (seq + 1) // 2
+        return 3 * batch * (body + head + attn)
+
+    def decode_attention_work(self, kv_len: int) -> tuple[int, int]:
+        """(operations, bytes) of one layer's decode attention for one slot:
+        read ``kv_len`` rows of K and V, read q, write o."""
+        flops = 4 * self.heads * self.head_dim * kv_len
+        kv = 2 * kv_len * self.kv_heads * self.head_dim * BF16
+        qo = 2 * self.heads * self.head_dim * BF16
+        return flops, kv + qo
+
+    def flash_attention_work(self, batch: int, seq: int) -> tuple[int, int]:
+        """(operations, bytes) of one layer's causal attention forward over
+        ``batch`` rows of ``seq`` tokens: read q, k, v, write o."""
+        flops = 4 * batch * self.heads * self.head_dim * seq * (seq + 1) // 2
+        qo = 2 * batch * seq * self.heads * self.head_dim * BF16
+        kv = 2 * batch * seq * self.kv_heads * self.head_dim * BF16
+        return flops, qo + kv
+
+
+def build(c: dict) -> Model:
+    """The model of configuration file ``c`` (published keys, and
+    ``qk_norm`` for the per-head norm of q and k that Qwen3 has)."""
+    return Model(Spec(layers=c["num_hidden_layers"], d_model=c["hidden_size"],
+                      heads=c["num_attention_heads"],
+                      kv_heads=c["num_key_value_heads"],
+                      head_dim=c["head_dim"], d_ff=c["intermediate_size"],
+                      vocab=c["vocab_size"], rope_theta=float(c["rope_theta"]),
+                      eps=c["rms_norm_eps"], qk_norm=c["qk_norm"],
+                      tied=c["tie_word_embeddings"]))

@@ -2,8 +2,9 @@
 
     python chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The cell is an entry of ``BENCHMARK.json``; its configuration, traffic mix
-and per-layer metrics are data files under ``chipbench/`` found by name.
+The cell is an entry of ``BENCHMARK.json``; its configuration, traffic mix,
+plain reference and per-layer metrics are files under ``chipbench/`` found
+by name.
 The run makes the weights and inputs from ``--seed``, compiles (or loads
 from the compile cache) and warms the cell's programs, measures for
 ``--seconds``, checks what the timed path produced against the plain
@@ -73,27 +74,26 @@ class CompileCounter:
 
 
 def _model(cell):
+    """The configuration's reference model, the program's ``ModelConfig``
+    and the weights generator that the reference's layout gives."""
     import jax.numpy as jnp
 
     from harness import spec, weights
-    from harness.arith import Arch
 
     c = cell.config
-    arch = Arch.from_config(c)
-    gen = weights.make_generator(arch, tied=c["tie_word_embeddings"],
-                                 qk_norm=c["model_type"] == "qwen3",
-                                 dtype=jnp.dtype(c["dtype"]))
-    return arch, spec.program_config(c), spec.reference_spec(c), gen
+    model = spec.reference_model(c)
+    gen = weights.make_generator(model.layout(), model.layers,
+                                 jnp.dtype(c["dtype"]))
+    return model, spec.program_config(c), gen
 
 
-def _program_params(gen, pcfg, seed):
+def _program_params(model, gen, pcfg, seed):
     import jax
 
     from harness import weights
     from repro.models import lm
 
-    w = gen(weights.seed_words(seed))
-    params = weights.program_tree(w)
+    params = model.to_program(gen(weights.seed_words(seed)))
     weights.check_matches(params, jax.eval_shape(
         lambda: lm.init_lm(pcfg, jax.random.PRNGKey(0))))
     return jax.block_until_ready(params)
@@ -111,17 +111,17 @@ def serve_cell(cell, seed, seconds, trace_dir, clock, t_start, chips,
     from repro.serve.engine import DecodeEngine
 
     mix, e = cell.traffic, cell.traffic["engine"]
-    arch, pcfg, rspec, gen = _model(cell)
-    params = _program_params(gen, pcfg, seed)
+    model, pcfg, gen = _model(cell)
+    params = _program_params(model, gen, pcfg, seed)
     eng = DecodeEngine(pcfg, params, batch_slots=e["slots"],
                        max_seq=e["max_seq"], rng_seed=0, mode=e["mode"],
                        steps_per_sync=e["steps_per_sync"],
                        prefill_chunk=e["prefill_chunk"],
                        kv_layout=e["kv_layout"])
-    serve.warm_up(eng, mix, arch.vocab, seed)
-    items = (traffic.open_loop(mix, seed, seconds, arch.vocab)
+    serve.warm_up(eng, mix, model.vocab, seed)
+    items = (traffic.open_loop(mix, seed, seconds, model.vocab)
              if mix["kind"] == "open_loop"
-             else traffic.requests(mix, seed, mix["requests"], arch.vocab))
+             else traffic.requests(mix, seed, mix["requests"], model.vocab))
     loop = serve.Loop(eng, mix, items, seconds, clock,
                       trace_seconds=min(seconds, TRACE_SECONDS),
                       trace_dir=trace_dir)
@@ -136,12 +136,12 @@ def serve_cell(cell, seed, seconds, trace_dir, clock, t_start, chips,
                for t in picked]
     out["run"] = SimpleNamespace(
         records=loop.traced, span=serve.SPAN, slots=e["slots"],
-        steps_per_sync=e["steps_per_sync"], arch=arch)
+        steps_per_sync=e["steps_per_sync"], model=model, chips=chips)
     loop.eng = eng = params = None
     gc.collect()
     log(f"device bytes in use before the reference: {chip.bytes_in_use()}")
     t_ref = time.perf_counter()
-    gaps = check.served_gap(rspec, gen(weights.seed_words(seed)), samples,
+    gaps = check.served_gap(model, gen(weights.seed_words(seed)), samples,
                             control=control)
     log(f"compared {gaps['served_tokens']} served tokens of "
         f"{len(samples)} requests in {time.perf_counter() - t_ref:.1f} s")
@@ -159,14 +159,14 @@ def train_cell(cell, seed, seconds, trace_dir, clock, t_start, chips,
     from harness.train import SPAN, Trainer
 
     mix = cell.traffic
-    arch, pcfg, rspec, gen = _model(cell)
-    params = _program_params(gen, pcfg, seed)
-    batches = traffic.train_batches(mix, seed, arch.vocab)
+    model, pcfg, gen = _model(cell)
+    params = _program_params(model, gen, pcfg, seed)
+    batches = traffic.train_batches(mix, seed, model.vocab)
     first = np.asarray(batches[: mix["check_steps"]])
-    tr = Trainer(pcfg, rspec, mix, params, batches)
+    tr = Trainer(pcfg, model, mix, params, batches)
     del params, batches
-    prog = tr.first_steps(weights.from_program_tree,
-                          lambda: gen(weights.seed_words(seed)))
+    w0 = lambda: gen(weights.seed_words(seed))
+    prog = tr.first_steps(model.from_program, w0)
     jax.block_until_ready(tr.params)
     t0 = clock()
     out = tr.window(seconds, clock, trace_dir,
@@ -174,24 +174,26 @@ def train_cell(cell, seed, seconds, trace_dir, clock, t_start, chips,
     out["metrics"]["setup_s"] = t0 - t_start
     out["window"] = (t0, clock())
     out["memory_peak_bytes"] = chip.memory_peak_bytes(chips)
-    out["run"] = SimpleNamespace(records=[], span=SPAN, arch=arch,
-                                 batch=mix["batch"], seq=mix["seq_len"])
+    out["run"] = SimpleNamespace(records=[], span=SPAN, model=model,
+                                 chips=chips, batch=mix["batch"],
+                                 seq=mix["seq_len"])
     tr = None
     gc.collect()
     log(f"device bytes in use before the reference: {chip.bytes_in_use()}")
     t_ref = time.perf_counter()
-    refr = check.reference_train(rspec, gen(weights.seed_words(seed)),
-                                 list(first), mix["lr"])
+    devices = jax.devices()[:chips]
+    refr = check.reference_train(model, w0, list(first), mix["lr"],
+                                 devices=devices)
     gaps = check.compare_train(prog, refr)
     log(f"losses: program {prog['losses']} reference {refr['losses']} "
         f"(reference {time.perf_counter() - t_ref:.1f} s)")
     out["checks"] = _checks(gaps, cell.config["limits"]["train"])
     out["readings"] = {"program": gaps}
     if control:
-        for variant in ("float8", "half_batch"):
-            other = check.reference_train(
-                rspec, gen(weights.seed_words(seed)), list(first),
-                mix["lr"], variant=variant)
+        for variant in ("float8", "half_batch") + (
+                ("dropped_shards",) if mix.get("zero1") else ()):
+            other = check.reference_train(model, w0, list(first), mix["lr"],
+                                          variant=variant, devices=devices)
             out["readings"][variant] = check.compare_train(other, refr)
     return out
 

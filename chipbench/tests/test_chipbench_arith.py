@@ -1,14 +1,14 @@
-"""The operation and byte counts, against counts made by hand."""
+"""The operation and byte counts of the dense reference model, against
+counts made by hand."""
 import pytest
 
-from harness.arith import (Arch, decode_attention_work, decode_token_flops,
-                           flash_attention_work, roofline_share,
-                           train_step_flops)
+from harness.arith import roofline_share
 from harness.chip import PEAKS, NoChip, peaks
+from reference.dense_gqa import Model, Spec
 
 # 2 layers, d 8, 4 heads of 2 (kv 2), ffn 16, vocab 10
-A = Arch(layers=2, d_model=8, heads=4, kv_heads=2, head_dim=2, d_ff=16,
-         vocab=10)
+A = Model(Spec(layers=2, d_model=8, heads=4, kv_heads=2, head_dim=2, d_ff=16,
+               vocab=10, rope_theta=1e4, eps=1e-6, qk_norm=False))
 
 
 def test_layer_params_by_hand():
@@ -20,7 +20,7 @@ def test_layer_params_by_hand():
 def test_decode_token_flops_by_hand():
     # 2 per multiply-add over (2 layers x 576 + head 80) weights, and
     # q.k plus p.v: 2 x 2 x heads x head_dim per key per layer
-    assert decode_token_flops(A, 5) == 2 * (2 * 576 + 80) + 2 * 4 * 4 * 2 * 5
+    assert A.decode_token_flops(5) == 2 * (2 * 576 + 80) + 2 * 4 * 4 * 2 * 5
 
 
 def test_train_step_flops_by_hand():
@@ -28,18 +28,18 @@ def test_train_step_flops_by_hand():
     body = S * 2 * 2 * 576
     head = (S - 1) * 2 * 80
     attn = 4 * 2 * 4 * 2 * (1 + 2 + 3 + 4)       # causal: 1..S keys
-    assert train_step_flops(A, B, S) == 3 * B * (body + head + attn)
+    assert A.train_step_flops(B, S) == 3 * B * (body + head + attn)
 
 
 def test_decode_attention_work_by_hand():
-    flops, nbytes = decode_attention_work(A, 7)
+    flops, nbytes = A.decode_attention_work(7)
     assert flops == 2 * 2 * 4 * 2 * 7
     # K and V rows (7 x 2 heads x 2 dims, bf16) plus q and o (4 x 2, bf16)
     assert nbytes == 2 * 7 * 2 * 2 * 2 + 2 * 4 * 2 * 2
 
 
 def test_flash_attention_work_by_hand():
-    flops, nbytes = flash_attention_work(A, 2, 3)
+    flops, nbytes = A.flash_attention_work(2, 3)
     assert flops == 4 * 2 * 4 * 2 * (1 + 2 + 3)
     assert nbytes == 2 * (2 * 3 * 4 * 2 * 2) + 2 * (2 * 3 * 2 * 2 * 2)
 

@@ -5,9 +5,9 @@ import os
 import pytest
 
 from harness import trace as T
-from harness.arith import Arch
 from harness.serve import StepRecord
 from harness.spec import metric_reader
+from reference.dense_gqa import Model, Spec
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures")
 # a decode attention call as the profile names it: 1 slot, 2 heads of 4
@@ -62,8 +62,10 @@ def _run(tr):
     r.peaks = {"bf16_flops": 1e12, "hbm_bytes_per_s": 1e9}
     r.steps_per_sync = 2
     r.slots = 1
-    r.arch = Arch(layers=1, d_model=8, heads=2, kv_heads=1, head_dim=4,
-                  d_ff=16, vocab=10)
+    r.model = Model(Spec(layers=1, d_model=8, heads=2, kv_heads=1,
+                         head_dim=4, d_ff=16, vocab=10, rope_theta=1e4,
+                         eps=1e-6, qk_norm=False))
+    r.chips = 1
     r.records = [StepRecord(0, 1, decode=[(3, 2)]), StepRecord(1, 2)]
     for rec, s in zip(r.records, tr.spans):
         rec.span = s

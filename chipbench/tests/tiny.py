@@ -17,14 +17,38 @@ def limits(config: str) -> dict:
 
 
 def config(model_type: str = "qwen3", dtype: str = "bfloat16", **kw) -> dict:
+    """A dense GQA configuration file: published keys, and the registry's
+    fields for them (qk-norm for ``qwen3``, none for ``llama``)."""
     c = {"name": "tiny", "model_type": model_type, "hidden_size": 64,
          "intermediate_size": 128, "num_hidden_layers": 2,
          "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
          "vocab_size": 512, "rope_theta": 10000.0, "rms_norm_eps": 1e-6,
          "tie_word_embeddings": True, "hidden_act": "silu", "dtype": dtype,
+         "family": "dense", "reference": "dense_gqa",
+         "qk_norm": model_type == "qwen3",
          "limits": {**limits("qwen3-4b"), **limits("smollm-360m")}}
     c.update(kw)
+    c["model_config"] = {
+        "num_layers": c["num_hidden_layers"], "d_model": c["hidden_size"],
+        "num_heads": c["num_attention_heads"],
+        "num_kv_heads": c["num_key_value_heads"], "head_dim": c["head_dim"],
+        "d_ff": c["intermediate_size"], "vocab_size": c["vocab_size"],
+        "qk_norm": c["qk_norm"], "rope_theta": float(c["rope_theta"]),
+        "act": c["hidden_act"], "norm_eps": c["rms_norm_eps"],
+        "tie_embeddings": c["tie_word_embeddings"], "dtype": c["dtype"]}
     return c
+
+
+def model(c: dict):
+    """The reference model of ``c`` and the generator of the weights its
+    layout gives, in the configuration's dtype."""
+    import jax.numpy as jnp
+
+    from harness import spec, weights
+
+    m = spec.reference_model(c)
+    return m, weights.make_generator(m.layout(), m.layers,
+                                     jnp.dtype(c["dtype"]))
 
 
 def serve_mix(kind: str = "open_loop") -> dict:
