@@ -19,8 +19,11 @@ def _attn_params(cfg: ModelConfig) -> int:
         m = cfg.mla
         qk_head = m.qk_nope_head_dim + m.qk_rope_head_dim
         n = 0
-        n += d * m.q_lora_rank + m.q_lora_rank  # q down (+norm)
-        n += m.q_lora_rank * cfg.num_heads * qk_head  # q up
+        if m.q_lora_rank:
+            n += d * m.q_lora_rank + m.q_lora_rank  # q down (+norm)
+            n += m.q_lora_rank * cfg.num_heads * qk_head  # q up
+        else:
+            n += d * cfg.num_heads * qk_head  # q projection
         n += d * (m.kv_lora_rank + m.qk_rope_head_dim) + m.kv_lora_rank
         n += m.kv_lora_rank * cfg.num_heads * (m.qk_nope_head_dim + m.v_head_dim)
         n += cfg.num_heads * m.v_head_dim * d  # o proj
@@ -55,12 +58,13 @@ def _dense_ffn_params(cfg: ModelConfig, width: int) -> int:
 
 
 def _moe_ffn_params(cfg: ModelConfig) -> tuple[int, int]:
-    """(total, active) params of one MoE FFN layer."""
+    """(total, active) params of one MoE FFN layer; total counts the
+    experts the layer holds."""
     m = cfg.moe
     per_exp = 3 * cfg.d_model * m.d_ff_expert
     router = cfg.d_model * m.num_experts
     shared = m.num_shared_experts * per_exp
-    total = m.num_experts * per_exp + router + shared
+    total = m.num_held * per_exp + router + shared
     active = m.top_k * per_exp + router + shared
     return total, active
 

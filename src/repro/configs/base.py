@@ -22,11 +22,21 @@ class MoEConfig:
     first_k_dense: int = 0
     every: int = 1
     aux_loss_coef: float = 0.01
-    capacity_factor: float = 1.25
     # 'softmax' (classic top-k) or 'sigmoid' (DeepSeek-V3 style scoring with
     # normalised top-k weights).
     scoring: str = "softmax"
     router_dtype: str = "float32"
+    # The routed experts this layer holds: [held_first, held_first +
+    # held_count).  The router still scores all ``num_experts``; assignments
+    # to experts held elsewhere (another chip of an expert-parallel
+    # deployment) contribute nothing here.  held_count 0: every expert from
+    # held_first on.
+    held_first: int = 0
+    held_count: int = 0
+
+    @property
+    def num_held(self) -> int:
+        return self.held_count or self.num_experts - self.held_first
 
 
 @dataclass(frozen=True)
@@ -47,7 +57,8 @@ class SSMConfig:
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-style Multi-head Latent Attention dims."""
+    """DeepSeek-style Multi-head Latent Attention dims.  ``q_lora_rank`` 0:
+    queries are projected directly, with no compression (DeepSeek-V2-Lite)."""
 
     q_lora_rank: int = 1536
     kv_lora_rank: int = 512
@@ -74,6 +85,15 @@ class ModelConfig:
     rope_theta: float = 10000.0
     rotary_pct: float = 1.0      # fraction of head_dim that is rotary
     rope_interleaved: bool = False  # GLM-style 2d/interleaved RoPE pairs
+    # YaRN context extension (DeepSeek-V2): the rotary frequencies blend
+    # interpolated and original ones, and attention's softmax scale grows by
+    # mscale(factor, yarn_mscale_all_dim)^2.  Factor 1 changes nothing.
+    yarn_factor: float = 1.0
+    yarn_original_max_positions: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- sub-configs --------------------------------------------------------
     moe: MoEConfig | None = None

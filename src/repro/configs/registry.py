@@ -13,6 +13,7 @@ _ARCH_MODULES = {
     "qwen3-4b": "repro.configs.qwen3_4b",
     "chatglm3-6b": "repro.configs.chatglm3_6b",
     "deepseek-v3-671b": "repro.configs.deepseek_v3_671b",
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
     "olmoe-1b-7b": "repro.configs.olmoe_1b_7b",
     "phi-3-vision-4.2b": "repro.configs.phi3_vision_4_2b",
     "jamba-v0.1-52b": "repro.configs.jamba_v0_1_52b",
@@ -43,8 +44,9 @@ def cells(include_inapplicable: bool = False):
 def reduced_config(name: str) -> ModelConfig:
     """A tiny same-family config for CPU smoke tests.
 
-    Keeps every structural feature of the full config (GQA ratio, MLA, MoE
-    top-k, hybrid interleave, codebooks ...) at toy width/depth.
+    Keeps every structural feature of the full config (GQA ratio, MLA with
+    or without query compression, YaRN, MoE top-k and a held share of the
+    experts, hybrid interleave, codebooks ...) at toy width/depth.
     """
     cfg = get_config(name)
     kw: dict = dict(
@@ -63,12 +65,17 @@ def reduced_config(name: str) -> ModelConfig:
     if cfg.d_ff_dense:
         kw.update(d_ff_dense=256)
     if cfg.moe is not None:
+        m = cfg.moe
+        share = m.held_first or m.held_count
         kw["moe"] = dataclasses.replace(
-            cfg.moe,
-            num_experts=min(cfg.moe.num_experts, 8),
-            top_k=min(cfg.moe.top_k, 2),
+            m,
+            num_experts=min(m.num_experts, 8),
+            top_k=min(m.top_k, 2),
             d_ff_expert=64,
-            first_k_dense=min(cfg.moe.first_k_dense, 1),
+            first_k_dense=min(m.first_k_dense, 1),
+            # a share keeps a share: half of the reduced experts
+            held_first=0,
+            held_count=min(m.num_experts, 8) // 2 if share else 0,
         )
     if cfg.ssm is not None:
         kw["ssm"] = dataclasses.replace(
@@ -76,7 +83,7 @@ def reduced_config(name: str) -> ModelConfig:
         )
     if cfg.mla is not None:
         kw["mla"] = MLAConfig(
-            q_lora_rank=64,
+            q_lora_rank=64 if cfg.mla.q_lora_rank else 0,
             kv_lora_rank=32,
             qk_nope_head_dim=32,
             qk_rope_head_dim=16,

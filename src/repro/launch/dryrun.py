@@ -91,8 +91,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         os.environ["REPRO_MOE"] = str(v["moe"])
     if v.get("flash_block"):              # KV block size of the flash path
         os.environ["REPRO_FLASH_BLOCK"] = str(v["flash_block"])
-    if v.get("moe_cf"):                   # MoE capacity factor override
-        os.environ["REPRO_MOE_CF"] = str(v["moe_cf"])
     if v.get("xent_chunk"):               # loss chunk length
         os.environ["REPRO_XENT_CHUNK"] = str(v["xent_chunk"])
     shape = get_shape(shape_name)

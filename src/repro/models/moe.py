@@ -1,20 +1,25 @@
 """Mixture-of-Experts FFN.
 
-Two dispatch strategies (selected with REPRO_MOE, default 'gather'):
+One dispatch, told which routed experts the layer holds
+(``MoEConfig.held_first``/``held_count``; all of them by default).  The
+router scores all ``num_experts``; the (token, expert) assignments to held
+experts are sorted by expert and run through ``jax.lax.ragged_dot`` with
+group sizes taken from the routing, so no assignment is ever dropped and
+no expert computes padding.  Assignments to experts held elsewhere add
+nothing here: on one chip of an expert-parallel deployment the other
+chips would compute them.
 
-* 'gather' — sorted-capacity dispatch under plain SPMD: the T*k (token,
-  expert) assignments are sorted by expert id, ranked within expert via a
-  running offset, and scattered into per-expert buffers [E, C, d].  Simple
-  and correct, but XLA SPMD resolves the token->expert scatter with global
-  gathers (the collective-bound baseline in §Perf).
+Where the installed rules split the held experts over mesh axes, each
+device runs the dispatch over its own slice of them (shard_map) and one
+psum over those axes combines the slices; two ways to place the tokens
+(selected with REPRO_MOE, default 'gather'):
 
-* 'ep' — beyond-paper optimisation: explicit expert parallelism with
-  shard_map.  Tokens stay sharded over the DP axes and are REPLICATED over
-  'model'; experts are sharded over 'model'.  Each device top-k routes its
-  local tokens, dispatches only to its local expert shard (local sort,
-  local capacity), and a single psum over 'model' combines expert outputs.
-  Per-MoE-layer collective traffic drops from O(T·d·E-shards gathers) to
-  one [T_local, d] all-reduce.
+* 'gather' — every device sees every token (gathered over the DP axes),
+  and the slices split over the 'experts' rule's axes.
+
+* 'ep' — explicit expert parallelism.  Tokens stay sharded over the DP
+  axes and are REPLICATED over 'model'; the held experts are split over
+  'model'.  Each device routes its local tokens.
 
 Scoring: 'softmax' (classic top-k, switch-style aux loss) or 'sigmoid'
 (DeepSeek-V3: sigmoid scores, top-k re-normalised).
@@ -35,15 +40,16 @@ from repro.sharding.rules import current_rules, shard
 
 def make_moe(cfg):
     d, m = cfg.d_model, cfg.moe
+    E = m.num_held
     p = {
         "router": Param((d, m.num_experts), ("embed", None), init="scaled",
                         dtype="float32"),
-        "wi": Param((m.num_experts, d, m.d_ff_expert),
-                    ("experts", "embed", None), init="scaled"),
-        "wg": Param((m.num_experts, d, m.d_ff_expert),
-                    ("experts", "embed", None), init="scaled"),
-        "wo": Param((m.num_experts, m.d_ff_expert, d),
-                    ("experts", None, "embed"), init="scaled"),
+        "wi": Param((E, d, m.d_ff_expert), ("experts", "embed", None),
+                    init="scaled"),
+        "wg": Param((E, d, m.d_ff_expert), ("experts", "embed", None),
+                    init="scaled"),
+        "wo": Param((E, m.d_ff_expert, d), ("experts", None, "embed"),
+                    init="scaled"),
     }
     if m.num_shared_experts:
         p["shared"] = make_dense_ffn(
@@ -54,6 +60,7 @@ def make_moe(cfg):
     return p
 
 
+@jax.named_scope("moe_route")
 def _route(cfg, p, x2d):
     """x2d: [T, d] -> (weights [T,k] f32, ids [T,k] i32, aux_loss f32)."""
     m = cfg.moe
@@ -77,52 +84,38 @@ def _route(cfg, p, x2d):
     return w, ids.astype(jnp.int32), aux
 
 
-def _capacity(cfg, T: int) -> int:
-    m = cfg.moe
-    cf = float(os.environ.get("REPRO_MOE_CF", m.capacity_factor))
-    c = int(T * m.top_k * cf / m.num_experts)
-    return max(8, -(-c // 8) * 8)  # round up to 8, at least 8
+def _dispatch_combine(cfg, p, x2d, w, ids, *, first, count):
+    """Dropless dispatch, expert FFNs and weighted combine over the experts
+    [first, first + count), whose weights ``p["wi"|"wg"|"wo"]`` hold
+    ``count`` experts.  ``first`` may be traced (a shard's offset inside
+    shard_map).  Returns [T, d]: each token's sum over its held experts of
+    routing weight times expert output.
 
-
-def _dispatch_combine(cfg, p, x2d, w, ids, *, num_experts, base_expert=0):
-    """Sorted-capacity dispatch + expert einsum + weighted combine over the
-    experts [base_expert, base_expert + num_experts).  Pure function of
-    local data — usable both under SPMD ('gather') and inside shard_map
-    ('ep', with per-shard expert slices).
-
-    p_wi/p_wg/p_wo must already be the local expert slice when
-    base_expert > 0 semantics are in play."""
-    m = cfg.moe
+    The rows are compacted to ``T * min(k, count)``: a token reaches the
+    held experts at most that often, so nothing can overflow."""
     T, d = x2d.shape
-    E, k = num_experts, m.top_k
-    C = _capacity(cfg, T)
-
-    flat_ids = ids.reshape(-1) - base_expert       # [T*k]; OOB -> dropped
-    in_range = (flat_ids >= 0) & (flat_ids < E)
-    flat_ids = jnp.where(in_range, flat_ids, E)
-    order = jnp.argsort(flat_ids, stable=True)
-    sorted_eid = flat_ids[order]
-    sorted_tok = order // k
-    counts = jnp.zeros((E + 1,), jnp.int32).at[flat_ids].add(1)
-    offsets = jnp.cumsum(counts) - counts
-    rank = jnp.arange(T * k, dtype=jnp.int32) - offsets[sorted_eid]
-    keep = (rank < C) & (sorted_eid < E)
-    slot = jnp.where(keep, sorted_eid * C + rank, E * C)
-    buf = jnp.zeros((E * C, d), x2d.dtype).at[slot].set(
-        x2d[sorted_tok], mode="drop")
-    buf = buf.reshape(E, C, d)
-
-    h = jnp.einsum("ecd,edf->ecf", buf, p["wi"])
-    g = jnp.einsum("ecd,edf->ecf", buf, p["wg"])
-    h = jax.nn.silu(g) * h
-    y_buf = jnp.einsum("ecf,efd->ecd", h, p["wo"]).reshape(E * C, d)
-
-    safe_slot = jnp.where(keep, slot, 0)
-    y_sorted = jnp.where(keep[:, None], y_buf[safe_slot], 0)
-    y_flat = jnp.zeros((T * k, d), x2d.dtype).at[order].set(y_sorted)
-    y = jnp.einsum("tkd,tk->td", y_flat.reshape(T, k, d),
-                   w.astype(x2d.dtype))
-    return y
+    k = ids.shape[1]
+    R = T * min(k, count)
+    with jax.named_scope("moe_dispatch"):
+        local = ids.reshape(-1) - first                    # [T*k]
+        held = (local >= 0) & (local < count)
+        key = jnp.where(held, local, count)                # others sort last
+        order = jnp.argsort(key, stable=True)[:R]
+        sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+        xs = x2d[order // k]                               # [R, d]
+    with jax.named_scope("moe_experts"):
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, p["wg"], sizes)) \
+            * jax.lax.ragged_dot(xs, p["wi"], sizes)
+        ys = jax.lax.ragged_dot(h, p["wo"], sizes)         # [R, d]
+    with jax.named_scope("moe_combine"):
+        # rows past sum(sizes) hold assignments held elsewhere: no group
+        # computes them, and they are dropped here
+        dest = jnp.where(jnp.arange(R) < sizes.sum(), order, T * k)
+        y_flat = jnp.zeros((T * k, d), ys.dtype).at[dest].set(
+            ys, mode="drop")
+        y = jnp.einsum("tkd,tk->td", y_flat.reshape(T, k, d),
+                       jnp.where(held, w.reshape(-1), 0.0).reshape(T, k))
+    return y.astype(x2d.dtype)
 
 
 def _moe_mode() -> str:
@@ -131,89 +124,75 @@ def _moe_mode() -> str:
 
 def apply_moe(cfg, p, x2d):
     """x2d: [T, d]. Returns (y [T, d], aux_loss scalar)."""
-    rules = current_rules()
-    if _moe_mode() == "ep" and rules is not None \
-            and "model" in rules.mesh.axis_names:
-        return apply_moe_ep(cfg, p, x2d, rules)
-    return apply_moe_gather(cfg, p, x2d)
-
-
-def apply_moe_gather(cfg, p, x2d):
-    """Baseline: SPMD sorted-capacity dispatch (paper-faithful layering)."""
     m = cfg.moe
-    T, d = x2d.shape
-    E, k = m.num_experts, m.top_k
-    C = _capacity(cfg, T)
-    w, ids, aux = _route(cfg, p, x2d)
-
-    # ---- sorted-capacity dispatch -------------------------------------
-    flat_ids = ids.reshape(-1)                      # [T*k]
-    order = jnp.argsort(flat_ids, stable=True)      # sort by expert
-    sorted_eid = flat_ids[order]
-    sorted_tok = order // k
-    counts = jnp.zeros((E,), jnp.int32).at[flat_ids].add(1)
-    offsets = jnp.cumsum(counts) - counts           # exclusive prefix
-    rank = jnp.arange(T * k, dtype=jnp.int32) - offsets[sorted_eid]
-    keep = rank < C
-    slot = jnp.where(keep, sorted_eid * C + rank, E * C)  # OOB -> dropped
-    buf = jnp.zeros((E * C, d), x2d.dtype).at[slot].set(
-        x2d[sorted_tok], mode="drop")
-    buf = shard(buf.reshape(E, C, d), "experts", None, None)
-
-    # ---- expert compute (batched over E; shards as EP) -----------------
-    h = jnp.einsum("ecd,edf->ecf", buf, p["wi"])
-    g = jnp.einsum("ecd,edf->ecf", buf, p["wg"])
-    h = jax.nn.silu(g) * h
-    h = shard(h, "experts", None, None)
-    y_buf = jnp.einsum("ecf,efd->ecd", h, p["wo"]).reshape(E * C, d)
-
-    # ---- combine back --------------------------------------------------
-    safe_slot = jnp.where(keep, slot, 0)
-    y_sorted = jnp.where(keep[:, None], y_buf[safe_slot], 0)
-    y_flat = jnp.zeros((T * k, d), x2d.dtype).at[order].set(y_sorted)
-    y = jnp.einsum("tkd,tk->td", y_flat.reshape(T, k, d), w.astype(x2d.dtype))
-
+    rules = current_rules()
+    tokens_dp = _moe_mode() == "ep" and _ep_fits(cfg, x2d, rules)
+    axes = ("model",) if tokens_dp else _expert_axes(cfg, rules)
+    if axes:
+        y, aux = _apply_split(cfg, p, x2d, rules, axes, tokens_dp)
+    else:
+        w, ids, aux = _route(cfg, p, x2d)
+        y = _dispatch_combine(cfg, p, x2d, w, ids, first=m.held_first,
+                              count=m.num_held)
     if m.num_shared_experts:
         y = y + apply_dense_ffn(cfg, p["shared"], x2d)
     return y, aux * m.aux_loss_coef
 
 
 # ---------------------------------------------------------------------------
-# explicit expert parallelism (shard_map) — §Perf optimisation
+# the held experts split over mesh axes (shard_map)
 # ---------------------------------------------------------------------------
-def apply_moe_ep(cfg, p, x2d, rules):
-    """Tokens DP-sharded / replicated over 'model'; experts sharded over
-    'model'; one psum combines.  Falls back to 'gather' when the expert
-    count does not divide the model axis."""
+def _expert_axes(cfg, rules) -> tuple[str, ...]:
+    """The mesh axes the installed rules split the held experts over; none
+    with no rules, or where the split would not be even."""
+    if rules is None:
+        return ()
+    axes = tuple(rules.table.get("experts", ()))
+    n = rules.axis_size(axes)
+    return axes if n > 1 and cfg.moe.num_held % n == 0 else ()
+
+
+def _ep_fits(cfg, x2d, rules) -> bool:
+    """Whether the mesh has a 'model' axis that splits the held experts and
+    data axes that split the tokens evenly."""
+    if rules is None or "model" not in rules.mesh.axis_names:
+        return False
+    shape = rules.mesh.shape
+    dp = 1
+    for a in ("pod", "data"):
+        dp *= shape.get(a, 1)
+    return cfg.moe.num_held % shape["model"] == 0 and x2d.shape[0] % dp == 0
+
+
+def _apply_split(cfg, p, x2d, rules, axes, tokens_dp):
+    """Each device holds an even slice of the held experts along ``axes``,
+    computes the routed output of its slice for the tokens it sees, and one
+    psum over ``axes`` combines the slices.  With ``tokens_dp`` ('ep') the
+    tokens stay sharded over the DP axes; otherwise ('gather') every device
+    sees every token."""
     m = cfg.moe
     mesh = rules.mesh
-    ep = mesh.shape["model"]
-    dp_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    dp_size = 1
-    for a in dp_axes:
-        dp_size *= mesh.shape[a]
-    T, d = x2d.shape
-    if m.num_experts % ep or T % dp_size:
-        return apply_moe_gather(cfg, p, x2d)
-    E_loc = m.num_experts // ep
-
-    x2d = shard(x2d, "batch", None)  # pin layout: rows over DP, repl. model
-    dp_spec = dp_axes[0] if len(dp_axes) == 1 else dp_axes
-
-    router = p["router"]
+    per = m.num_held // rules.axis_size(axes)
+    dp_axes = tuple(a for a in ("pod", "data")
+                    if tokens_dp and a in mesh.axis_names)
+    if tokens_dp:
+        x2d = shard(x2d, "batch", None)  # rows over DP, repl. model
+    dp_spec = dp_axes[0] if len(dp_axes) == 1 else (dp_axes or None)
+    ax_spec = axes[0] if len(axes) == 1 else axes
     bias = p.get("bias")
-    wi, wg, wo = p["wi"], p["wg"], p["wo"]
 
     def local(x_loc, router_w, bias_w, wi_l, wg_l, wo_l):
         pp = {"router": router_w, "wi": wi_l, "wg": wg_l, "wo": wo_l}
         if bias_w is not None:
             pp["bias"] = bias_w
         w, ids, aux = _route(cfg, pp, x_loc)
-        shard_id = jax.lax.axis_index("model")
+        shard_id = 0
+        for a in axes:                   # row-major over the split axes
+            shard_id = shard_id * mesh.shape[a] + jax.lax.axis_index(a)
         y_loc = _dispatch_combine(cfg, pp, x_loc, w, ids,
-                                  num_experts=E_loc,
-                                  base_expert=shard_id * E_loc)
-        y = jax.lax.psum(y_loc, "model")
+                                  first=m.held_first + shard_id * per,
+                                  count=per)
+        y = jax.lax.psum(y_loc, axes)
         aux = jax.lax.pmean(aux, dp_axes) if dp_axes else aux
         return y, aux
 
@@ -221,15 +200,12 @@ def apply_moe_ep(cfg, p, x2d, rules):
         P(dp_spec, None),            # x2d
         P(None, None),               # router
         P(None) if bias is not None else None,
-        P("model", None, None),      # wi  [E, d, ff]
-        P("model", None, None),      # wg
-        P("model", None, None),      # wo  [E, ff, d]
+        P(ax_spec, None, None),      # wi  [E, d, ff]
+        P(ax_spec, None, None),      # wg
+        P(ax_spec, None, None),      # wo  [E, ff, d]
     )
     fn = partial(jax.shard_map, mesh=mesh,
                  in_specs=in_specs,
                  out_specs=(P(dp_spec, None), P()),
                  check_vma=False)(local)
-    y, aux = fn(x2d, router, bias, wi, wg, wo)
-    if m.num_shared_experts:
-        y = y + apply_dense_ffn(cfg, p["shared"], x2d)
-    return y, aux * m.aux_loss_coef
+    return fn(x2d, p["router"], bias, p["wi"], p["wg"], p["wo"])

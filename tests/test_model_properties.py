@@ -109,14 +109,31 @@ def test_moe_active_params_much_smaller_than_total():
     assert pc.active < pc.total / 10
 
 
-@given(st.integers(1, 8), st.integers(1, 8))
+@given(st.integers(0, 7), st.integers(1, 8), st.integers(1, 40),
+       st.integers(0, 2 ** 16))
 @settings(max_examples=20, deadline=None)
-def test_capacity_is_sufficient_for_uniform_routing(e_pow, k):
-    """capacity * E >= T * k (no drops under perfectly uniform routing)."""
-    from repro.models.moe import _capacity
+def test_dispatch_computes_every_held_assignment(first, count, T, seed):
+    """Whatever the routing and the held range, each token's output is the
+    sum over its assignments to held experts of weight times that expert's
+    FFN: nothing is dropped, nothing held elsewhere is added."""
+    import dataclasses
 
-    cfg = reduced_config("olmoe-1b-7b")
-    T = 64 * e_pow
-    c = _capacity(cfg, T)
-    E = cfg.moe.num_experts
-    assert c * E >= T * cfg.moe.top_k
+    from repro.models.moe import _dispatch_combine, _route, make_moe
+    from repro.models.params import init_params
+
+    cfg = reduced_config("olmoe-1b-7b").replace(dtype="float32")
+    count = min(count, cfg.moe.num_experts - first)
+    cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, held_first=first,
+                                              held_count=count))
+    p = init_params(make_moe(cfg), jax.random.PRNGKey(seed))
+    x = jax.random.normal(jax.random.PRNGKey(seed + 1), (T, cfg.d_model))
+    w, ids, _ = _route(cfg, p, x)
+    got = _dispatch_combine(cfg, p, x, w, ids, first=first, count=count)
+    want = np.zeros((T, cfg.d_model), np.float32)
+    for t in range(T):
+        for j in range(cfg.moe.top_k):
+            e = int(ids[t, j]) - first
+            if 0 <= e < count:
+                h = jax.nn.silu(x[t] @ p["wg"][e]) * (x[t] @ p["wi"][e])
+                want[t] += float(w[t, j]) * np.asarray(h @ p["wo"][e])
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)

@@ -1,5 +1,6 @@
-"""MoE dispatch strategies: gather (SPMD baseline) vs ep (shard_map expert
-parallelism) must agree; routing properties."""
+"""MoE dispatch placements: one device, gather (every token, the experts
+split over the mesh) and ep (tokens split over data too) must agree;
+routing properties."""
 import os
 import subprocess
 import sys
@@ -11,16 +12,15 @@ import numpy as np
 import pytest
 
 from repro.configs import reduced_config
-from repro.models.moe import _capacity, _route, apply_moe_gather, make_moe
+from repro.models.moe import _route, make_moe
 from repro.models.params import init_params
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _cfg(scoring="softmax", cf=4.0):
+def _cfg(scoring="softmax"):
     cfg = reduced_config("olmoe-1b-7b")
-    return cfg.replace(moe=dataclasses.replace(
-        cfg.moe, scoring=scoring, capacity_factor=cf))
+    return cfg.replace(moe=dataclasses.replace(cfg.moe, scoring=scoring))
 
 
 def test_route_topk_weights_normalised_sigmoid():
@@ -32,17 +32,6 @@ def test_route_topk_weights_normalised_sigmoid():
     np.testing.assert_allclose(np.asarray(jnp.sum(w, 1)), 1.0, atol=1e-5)
     assert ids.shape == (32, cfg.moe.top_k)
     assert bool(jnp.isfinite(aux))
-
-
-def test_gather_dispatch_handles_capacity_overflow():
-    """With capacity_factor tiny, outputs stay finite and bounded."""
-    cfg = _cfg(cf=0.1)
-    p = init_params(make_moe(cfg), jax.random.PRNGKey(0))
-    x = jax.random.normal(jax.random.PRNGKey(1), (64, cfg.d_model),
-                          jnp.bfloat16)
-    y, aux = apply_moe_gather(cfg, p, x)
-    assert y.shape == x.shape
-    assert bool(jnp.all(jnp.isfinite(y.astype(jnp.float32))))
 
 
 def test_aux_loss_penalises_imbalance():
@@ -70,7 +59,6 @@ from repro.models.params import init_params, param_shardings
 from repro.sharding.rules import make_rules, use_rules
 
 cfg = reduced_config("olmoe-1b-7b")
-cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, capacity_factor=4.0))
 descr = lm.make_lm(cfg)
 params = init_params(descr, jax.random.PRNGKey(0))
 tok = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.vocab_size)
@@ -81,7 +69,7 @@ mesh = make_mesh((2, 4), ("data", "model"))
 rules = make_rules(mesh)
 psh = param_shardings(descr, rules)
 ps = jax.tree_util.tree_map(jax.device_put, params, psh)
-os.environ["REPRO_MOE"] = "ep"
+os.environ["REPRO_MOE"] = MODE
 def f(p, b):
     with use_rules(rules):
         return lm.train_loss(cfg, p, b, remat=False)
@@ -92,10 +80,22 @@ print("EP_PARITY_OK")
 """
 
 
-def test_ep_dispatch_parity_subprocess():
+def _parity(mode):
     env = dict(os.environ, PYTHONPATH="src")
-    r = subprocess.run([sys.executable, "-c", EP_PARITY],
+    r = subprocess.run([sys.executable, "-c",
+                        f"MODE = {mode!r}\n" + EP_PARITY],
                        capture_output=True, text=True, env=env,
                        timeout=480, cwd=ROOT)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
     assert "EP_PARITY_OK" in r.stdout
+
+
+def test_ep_dispatch_parity_subprocess():
+    _parity("ep")
+
+
+def test_split_gather_parity_subprocess():
+    """'gather' on a (data 2, model 4) mesh: each device computes the
+    routed output of its quarter of the experts for every token, and the
+    psum over 'model' matches one device."""
+    _parity("gather")

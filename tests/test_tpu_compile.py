@@ -277,6 +277,56 @@ def test_step_programs_write_the_kv_cache_in_place(one_chip,
     assert _cache_sized_copies(chunk, shapes) == []
 
 
+def test_deepseek_v2_lite_share_fits_and_names_its_scopes(one_chip,
+                                                         kernel_dispatch,
+                                                         no_compile_cache):
+    """The ``deepseek-v2-lite-ep8.reason`` cell's programs, all 27 layers
+    with routed experts 0-7 held, 32 slots x 4096 of latent cache: the
+    fused decode (8 steps) and the 128-token prefill chunk fit a v5e's
+    16 GB with room, the experts run as XLA's ragged dot, and the scopes
+    that the cell's per-layer metrics read are in the operations' names."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models.params import abstract_params
+    from repro.serve.engine import _fused_steps, _prefill_chunk
+
+    B, S, C = 32, 4096, 128
+    cfg = get_config("deepseek-v2-lite")
+    cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, held_count=8))
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+    arg = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    params = on_chip(abstract_params(lm.make_lm(cfg)))
+    cache = on_chip(abstract_params(lm.make_cache(cfg, B, S)))
+    state = {"tokens": arg((B, 1)), "pos": arg((B,)), "cursor": arg((B,)),
+             "plen": arg((B,)), "remaining": arg((B,)),
+             "live": arg((B,), bool), "keys": arg((B, 2), jnp.uint32)}
+    fused = _fused_steps.lower(
+        cfg, 8, params, cache, state, arg((B, S)), arg((B,), jnp.float32),
+        arg((B,)), None, None).compile()
+    chunk = _prefill_chunk.lower(
+        cfg, params, cache, arg((B, C)), arg((B,)), arg((B,), bool),
+        None).compile()
+    for program, scopes in ((fused, ("mla_latent_attention", "moe_route",
+                                     "moe_dispatch", "moe_experts",
+                                     "moe_combine")),
+                            (chunk, ("mla_latent_attention", "moe_experts"))):
+        mem = program.memory_analysis()
+        peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        assert peak < 0.9 * 16e9, peak
+        hlo = program.as_text()
+        assert "ragged-dot" in hlo
+        op_names = _op_names(hlo)
+        for scope in scopes:
+            assert any(_in_scope(n, scope) and _in_scope(n, "mla" if
+                       scope.startswith("mla") else "moe")
+                       for n in op_names), scope
+
+
 def test_train_step_names_the_attention_backward(one_chip, kernel_dispatch,
                                                  no_compile_cache):
     """The chip's train step runs the flash kernel forward and the XLA
