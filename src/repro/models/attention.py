@@ -199,15 +199,16 @@ def apply_attention_prefill_chunk_paged(cfg, p, x, cache, start, page_table,
     return out.astype(x.dtype) @ p["wo"], {"k": k, "v": v}
 
 
-def _write_rows(buf, rows, layer, start, active):
+def write_rows(buf, rows, layer, start, active):
     """Write each slot's new rows into the cache buffer, in place.
 
     buf: [K, B, Smax, hd], or with ``layer`` (an int32 scalar) the stack
     [L, K, B, Smax, hd] written at that layer; rows: [K, B, C, hd], slot
-    b's rows for positions start[b] .. start[b]+C-1; active: optional [B]
-    bool.  Rows of an inactive slot, and rows at or past Smax, are
-    dropped.  Both forms keep the buffer in its own layout, so XLA updates
-    it where it lies:
+    b's rows for positions start[b] .. start[b]+C-1.  An MLA latent cache
+    has no KV-head axis: buf [(L,) B, Smax, r], rows [B, C, r].  active:
+    optional [B] bool.  Rows of an inactive slot, and rows at or past
+    Smax, are dropped.  Both forms keep the buffer in its own layout, so
+    XLA updates it where it lies:
 
     * one row a slot (decode): one scatter whose window is one head_dim
       row, its indices naming layer, KV head, slot and position.  With
@@ -215,28 +216,30 @@ def _write_rows(buf, rows, layer, start, active):
       and copies it whole to feed the kernel.
     * a chunk: one ``dynamic_update_slice`` of the slot's [K, C, hd]
       block per slot.  A scatter costs the chip per index, K·B·C of them
-      (37 of a 134 ms chunk at qwen3-4b's widths on a TPU v5e).  The
-      update clamps its start, so a block that would reach past Smax
-      lands lower and its rows before ``start`` write back what they
-      read."""
+      (37 of a 134 ms chunk at qwen3-4b's widths on a TPU v5e), and a
+      B·C-row scatter into MLA's rope stack [L, B, Smax, 64] has XLA lay
+      the whole stack out anew around it, every layer.  The update clamps
+      its start, so a block that would reach past Smax lands lower and
+      its rows before ``start`` write back what they read."""
     at = () if layer is None else (layer,)
-    K, B, smax, hd = buf.shape[-4:]
-    C = rows.shape[2]
+    heads = rows.shape[:-3]             # (K,), or () for a latent cache
+    B, C, hd = rows.shape[-3:]
+    smax = buf.shape[-2]
     if C == 1:
         pos = start[:, None] if active is None else jnp.where(
             active[:, None], start[:, None], smax)
-        return buf.at[(*at, jnp.arange(K)[:, None, None],
-                       jnp.arange(B)[None, :, None], pos[None])].set(
-                           rows, mode="drop")
+        kv = (jnp.arange(heads[0])[:, None, None],) if heads else ()
+        return buf.at[(*at, *kv, jnp.arange(B)[:, None], pos)].set(
+            rows, mode="drop")
     lo = jnp.clip(start, 0, smax - C)
-    size = (1,) * len(at) + (K, 1, C, hd)
+    size = (1,) * len(at) + (*heads, 1, C, hd)
     for b in range(B):
-        idx = (*at, 0, b, lo[b], 0)
-        old = jax.lax.dynamic_slice(buf, idx, size).reshape(K, C, hd)
+        idx = (*at, *(0,) * len(heads), b, lo[b], 0)
+        old = jax.lax.dynamic_slice(buf, idx, size).reshape(*heads, C, hd)
         src = jnp.arange(C) - (start[b] - lo[b])    # row of ``rows`` here
-        new = jnp.take(rows[:, b], jnp.clip(src, 0, C - 1), axis=1)
+        new = jnp.take(rows[..., b, :, :], jnp.clip(src, 0, C - 1), axis=-2)
         ok = src >= 0 if active is None else active[b] & (src >= 0)
-        blk = jnp.where(ok[None, :, None], new, old)
+        blk = jnp.where(ok[:, None], new, old)
         buf = jax.lax.dynamic_update_slice(buf, blk.reshape(size), idx)
     return buf
 
@@ -260,10 +263,10 @@ def apply_attention_prefill_chunk(cfg, p, x, cache, start, active=None,
     B, C, _ = x.shape
     positions = start[:, None] + jnp.arange(C)[None, :]         # [B, C]
     q, k_new, v_new = _qkv(cfg, p, x, positions)
-    new = {"k": _write_rows(cache["k"], _kv_major(k_new), layer, start,
-                            active),
-           "v": _write_rows(cache["v"], _kv_major(v_new), layer, start,
-                            active)}
+    new = {"k": write_rows(cache["k"], _kv_major(k_new), layer, start,
+                           active),
+           "v": write_rows(cache["v"], _kv_major(v_new), layer, start,
+                           active)}
     k, v = new["k"], new["v"]
     if layer is not None:
         k, v = (jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
@@ -281,8 +284,8 @@ def apply_attention_decode(cfg, p, x, cache, pos, active=None, layer=None):
     untouched (continuous batching).  Returns (out, new_cache)."""
     B = x.shape[0]
     q, k_new, v_new = _qkv(cfg, p, x, pos[:, None])
-    k = _write_rows(cache["k"], _kv_major(k_new), layer, pos, active)
-    v = _write_rows(cache["v"], _kv_major(v_new), layer, pos, active)
+    k = write_rows(cache["k"], _kv_major(k_new), layer, pos, active)
+    v = write_rows(cache["v"], _kv_major(v_new), layer, pos, active)
     # position p attended iff p <= pos, i.e. p < pos + 1 == kv_len.  The
     # dispatcher's ref path is bit-identical to the previous inline einsum
     # formulation; on TPU / REPRO_PALLAS=interpret the Sq=1 Pallas decode

@@ -123,7 +123,7 @@ def apply_block_decode(cfg, p, h, cache, pos, mixer: str, ffn: str,
     """One-token decode. ``page_table`` not None selects the paged cache
     layout for attention/MLA mixers (mamba state is dense either way).
     ``layer`` not None: ``cache`` is the whole layer stack of a dense
-    attention segment, written and read at that layer in place.
+    attention or MLA segment, written and read at that layer in place.
     Returns (h, new_cache)."""
     with jax.named_scope(MIXER_SCOPE[mixer]):
         x = rmsnorm(h, p["ln1"], cfg.norm_eps)
@@ -141,7 +141,8 @@ def apply_block_decode(cfg, p, h, cache, pos, mixer: str, ffn: str,
                                 active)
                             if page_table is not None
                             else mla_mod.apply_mla_decode(cfg, p["mixer"], x,
-                                                          cache, pos, active))
+                                                          cache, pos, active,
+                                                          layer))
         else:
             r, new_cache = mamba_mod.apply_mamba_decode(
                 cfg, p["mixer"], x, cache, pos, active)
@@ -171,7 +172,8 @@ def apply_block_prefill_chunk(cfg, p, h, cache, start, mixer: str, ffn: str,
                                 active)
                             if page_table is not None
                             else mla_mod.apply_mla_prefill_chunk(
-                                cfg, p["mixer"], x, cache, start, active))
+                                cfg, p["mixer"], x, cache, start, active,
+                                layer))
         else:
             r, new_cache = mamba_mod.apply_mamba_prefill_chunk(
                 cfg, p["mixer"], x, cache, start, active)

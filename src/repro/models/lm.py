@@ -316,16 +316,16 @@ def _cache_scan(cfg, params, h, cache, page_table, apply, unroll):
     (the step programs donate it).
 
     ``apply(seg, layer_p, h, c, layer)`` runs one layer and returns
-    ``(h, c)``.  A dense attention segment (no page table) hands it the
-    whole stack and the layer index: attention writes its new rows and
-    reads the layer in place.  Other segments (MLA, mamba, the hybrid
+    ``(h, c)``.  An attention or MLA segment with no page table hands it
+    the whole stack and the layer index: the mixer writes its new rows and
+    reads the layer in place.  Other segments (mamba, the hybrid
     super-block, a paged pool) get ``layer`` None and the layer's own
     leaves, read out of the carry and written back after.
     Returns (h, new_cache)."""
     new_caches = []
     for seg, seg_params, seg_cache in zip(segments(cfg), params["segments"],
                                           cache, strict=False):
-        whole = (seg.kind == "blocks" and seg.mixer == "attn"
+        whole = (seg.kind == "blocks" and seg.mixer in ("attn", "mla")
                  and page_table is None)
 
         def body(carry, xs, seg=seg, whole=whole):

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
+from repro.models.attention import paged_write_rows, write_rows
 from repro.models.layers import rmsnorm
 from repro.models.params import Param
 from repro.models.rope import apply_rope, get_mscale, yarn_inv_freq
@@ -138,26 +139,33 @@ def make_mla_cache_paged(cfg, num_pages: int, page_size: int,
     }
 
 
-def _absorbed_attention(cfg, p, q_nope, q_pe, ckv, kpe, positions, dtype):
+def _absorbed_attention(cfg, p, q_nope, q_pe, ckv, kpe, positions, dtype,
+                        layer=None):
     """Weight-absorbed attention of queries at ``positions`` [B, Q] against
     a latent cache ``ckv`` [B, S, r], ``kpe`` [B, S, rope]: W_UK folds into
     the queries, W_UV into the output, so the cache is read as it is
-    stored.  Returns [B, Q, H * v_head_dim] in ``dtype``."""
+    stored.  With ``layer`` (an int32 scalar) ``ckv`` and ``kpe`` are the
+    stacks [L, B, S, *], read at that layer by each einsum that takes
+    them, so the slice fuses into the einsum and is never copied out.
+    Returns [B, Q, H * v_head_dim] in ``dtype``."""
     B, Q = positions.shape
     m, H = cfg.mla, cfg.num_heads
+    read = (lambda c: c) if layer is None else (
+        lambda c: jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False))
     wuk = p["wuk"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim)
     q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope.astype(jnp.float32),
                        wuk.astype(jnp.float32))
     with jax.named_scope(LATENT_SCOPE):
-        ckv32 = ckv.astype(jnp.float32)
-        scores = jnp.einsum("bqhr,bsr->bhqs", q_lat, ckv32)
+        scores = jnp.einsum("bqhr,bsr->bhqs", q_lat,
+                            read(ckv).astype(jnp.float32))
         scores += jnp.einsum("bqhd,bsd->bhqs", q_pe.astype(jnp.float32),
-                             kpe.astype(jnp.float32))
+                             read(kpe).astype(jnp.float32))
         scores *= softmax_scale(cfg)
-        mask = jnp.arange(ckv.shape[1])[None, None, :] <= positions[:, :, None]
+        mask = jnp.arange(ckv.shape[-2])[None, None, :] <= positions[:, :, None]
         scores = jnp.where(mask[:, None, :, :], scores, -jnp.inf)
         probs = jax.nn.softmax(scores, axis=-1)
-        o_lat = jnp.einsum("bhqs,bsr->bqhr", probs, ckv32)
+        o_lat = jnp.einsum("bhqs,bsr->bqhr", probs,
+                           read(ckv).astype(jnp.float32))
     wuv = p["wuv"].reshape(m.kv_lora_rank, H, m.v_head_dim)
     out = jnp.einsum("bqhr,rhd->bqhd", o_lat, wuv.astype(jnp.float32))
     return out.reshape(B, Q, H * m.v_head_dim).astype(dtype)
@@ -170,7 +178,6 @@ def apply_mla_prefill_chunk_paged(cfg, p, x, cache, start, page_table,
     replaced by page-table scatter + gather (stale rows sit beyond the
     causal mask)."""
     from repro.kernels.ref import gather_pages
-    from repro.models.attention import paged_write_rows
 
     C = x.shape[1]
     positions = start[:, None] + jnp.arange(C)[None, :]          # [B, C]
@@ -192,7 +199,6 @@ def apply_mla_decode_paged(cfg, p, x, cache, pos, page_table, active=None):
     x: [B,1,d]; cache {ckv: [P,ps,r], kpe: [P,ps,rope]}; pos: [B];
     page_table: [B,W] int32; active: optional [B] bool."""
     from repro.kernels.ref import gather_pages
-    from repro.models.attention import paged_write_rows
 
     q_nope, q_pe = _queries(cfg, p, x, pos[:, None])  # [B,1,H,*]
     ckv_new, kpe_new = _latent_kv(cfg, p, x, pos[:, None])
@@ -207,41 +213,38 @@ def apply_mla_decode_paged(cfg, p, x, cache, pos, page_table, active=None):
     return out @ p["wo"], {"ckv": ckv, "kpe": kpe}
 
 
-def apply_mla_prefill_chunk(cfg, p, x, cache, start, active=None):
+def apply_mla_prefill_chunk(cfg, p, x, cache, start, active=None,
+                            layer=None):
     """Weight-absorbed prefill of a C-token chunk into the latent cache.
 
-    x: [B, C, d]; cache {ckv: [B,S,r], kpe: [B,S,rope]}; start: [B] int32
-    (per-slot cache position of the chunk's first token); active: optional
-    [B] bool — inactive slots leave the cache untouched, outputs garbage.
-    Returns (out [B, C, d], new_cache)."""
-    B, C, _ = x.shape
+    x: [B, C, d]; cache {ckv: [B,S,r], kpe: [B,S,rope]}, or with ``layer``
+    (an int32 scalar) the whole stacks {ckv: [L,B,S,r], kpe: [L,B,S,rope]},
+    of which layer ``layer`` is written and read in place; start: [B]
+    int32 (per-slot cache position of the chunk's first token); active:
+    optional [B] bool — inactive slots leave the cache untouched, outputs
+    garbage.  Returns (out [B, C, d], new_cache)."""
+    C = x.shape[1]
     positions = start[:, None] + jnp.arange(C)[None, :]          # [B, C]
     q_nope, q_pe = _queries(cfg, p, x, positions)                # [B,C,H,*]
     ckv_new, kpe_new = _latent_kv(cfg, p, x, positions)
-    smax = cache["ckv"].shape[1]
-    wpos = positions if active is None else jnp.where(
-        active[:, None], positions, smax)
-    b_idx = jnp.arange(B)[:, None]
-    ckv = cache["ckv"].at[b_idx, wpos, ...].set(ckv_new, mode="drop")
-    kpe = cache["kpe"].at[b_idx, wpos, ...].set(kpe_new, mode="drop")
+    ckv = write_rows(cache["ckv"], ckv_new, layer, start, active)
+    kpe = write_rows(cache["kpe"], kpe_new, layer, start, active)
     out = _absorbed_attention(cfg, p, q_nope, q_pe, ckv, kpe, positions,
-                              x.dtype)
+                              x.dtype, layer)
     return out @ p["wo"], {"ckv": ckv, "kpe": kpe}
 
 
-def apply_mla_decode(cfg, p, x, cache, pos, active=None):
+def apply_mla_decode(cfg, p, x, cache, pos, active=None, layer=None):
     """Weight-absorbed one-token decode.
 
-    x: [B,1,d]; cache {ckv: [B,S,r], kpe: [B,S,rope]}; pos: [B];
+    x: [B,1,d]; cache {ckv: [B,S,r], kpe: [B,S,rope]}, or with ``layer``
+    (an int32 scalar) the whole stacks {ckv: [L,B,S,r], kpe: [L,B,S,rope]},
+    of which layer ``layer`` is written and read in place; pos: [B];
     active: optional [B] bool (inactive slots leave the cache untouched)."""
-    B = x.shape[0]
     q_nope, q_pe = _queries(cfg, p, x, pos[:, None])  # [B,1,H,*]
     ckv_new, kpe_new = _latent_kv(cfg, p, x, pos[:, None])
-    b_idx = jnp.arange(B)
-    smax = cache["ckv"].shape[1]
-    wpos = pos if active is None else jnp.where(active, pos, smax)
-    ckv = cache["ckv"].at[b_idx, wpos, ...].set(ckv_new[:, 0], mode="drop")
-    kpe = cache["kpe"].at[b_idx, wpos, ...].set(kpe_new[:, 0], mode="drop")
+    ckv = write_rows(cache["ckv"], ckv_new, layer, pos, active)
+    kpe = write_rows(cache["kpe"], kpe_new, layer, pos, active)
     out = _absorbed_attention(cfg, p, q_nope, q_pe, ckv, kpe, pos[:, None],
-                              x.dtype)
+                              x.dtype, layer)
     return out @ p["wo"], {"ckv": ckv, "kpe": kpe}

@@ -1,6 +1,7 @@
 """The step programs carry each segment's stacked cache through the layer
-scan (``lm._cache_scan``): a dense attention layer writes its rows and
-reads its layer in place, every other route reads its layer out of the
+scan (``lm._cache_scan``): a dense attention or MLA layer with no page
+table writes its rows and reads its layer in place; every other route
+(paged pools, mamba, the hybrid super-block) reads its layer out of the
 carry and writes it back.  Either way ``lm.decode_step`` and
 ``lm.prefill_chunk`` must give bit for bit what a plain loop over layers
 gives, each layer's cache sliced out, run through the block function and
@@ -102,8 +103,8 @@ def _same(a, b):
 
 # (arch, paged): each route of the layer scan
 ROUTES = [("smollm-360m", False), ("smollm-360m", True),
-          ("deepseek-v3-671b", False), ("mamba2-130m", False),
-          ("jamba-v0.1-52b", False)]
+          ("deepseek-v3-671b", False), ("deepseek-v2-lite", False),
+          ("mamba2-130m", False), ("jamba-v0.1-52b", False)]
 
 
 @pytest.mark.parametrize("arch,paged", ROUTES)

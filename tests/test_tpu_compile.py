@@ -284,7 +284,15 @@ def test_deepseek_v2_lite_share_fits_and_names_its_scopes(one_chip,
     with routed experts 0-7 held, 32 slots x 4096 of latent cache: the
     fused decode (8 steps) and the 128-token prefill chunk fit a v5e's
     16 GB with room, the experts run as XLA's ragged dot, and the scopes
-    that the cell's per-layer metrics read are in the operations' names."""
+    that the cell's per-layer metrics read are in the operations' names.
+
+    Both programs carry the stacked latent cache in place: no copy and no
+    dynamic-update-slice fusion of a layer's ``ckv`` [B, S, 512] or of the
+    26 MoE layers' ``ckv`` and ``kpe`` stacks.  What remains is the dense
+    first layer's own ``kpe`` [1, B, S, 64] in the fused decode: laid out
+    anew at the program's edges, once a call (two copies), and moved into
+    and out of fast memory (two copy-dones).  Its count is pinned here so
+    that it cannot grow; the prefill chunk has none."""
     import dataclasses
 
     from repro.configs import get_config
@@ -310,6 +318,15 @@ def test_deepseek_v2_lite_share_fits_and_names_its_scopes(one_chip,
     chunk = _prefill_chunk.lower(
         cfg, params, cache, arg((B, C)), arg((B,)), arg((B,), bool),
         None).compile()
+    m, moe_layers = cfg.mla, cfg.num_layers - 1
+    stacks_and_slices = {(moe_layers, B, S, m.kv_lora_rank),
+                         (B, S, m.kv_lora_rank),
+                         (moe_layers, B, S, m.qk_rope_head_dim)}
+    rope_slice = {(B, S, m.qk_rope_head_dim)}
+    for program, edge_copies in ((fused, 4), (chunk, 0)):
+        hlo = program.as_text()
+        assert _cache_sized_copies(hlo, stacks_and_slices) == []
+        assert len(_cache_sized_copies(hlo, rope_slice)) <= edge_copies
     for program, scopes in ((fused, ("mla_latent_attention", "moe_route",
                                      "moe_dispatch", "moe_experts",
                                      "moe_combine")),
